@@ -705,6 +705,9 @@ def main(argv=None) -> None:
                     flags + " --xla_force_host_platform_device_count=8"
                 ).strip()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     skipped: list[str] = []  # every scenario a mode drops, logged explicitly
     print("name,tokens_per_s,derived")
 

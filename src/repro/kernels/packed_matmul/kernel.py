@@ -34,30 +34,32 @@ recovery costs one XOR per segment.
 The reduction runs on a 3-D ``(m, n, k)`` grid with the K axis
 innermost, so one ``[bm, bk] x [bk, bnp]`` tile pair is resident in VMEM
 per step instead of the whole K dimension, and the grid-level pipeline
-overlaps the next tile's DMA with the current tile's compute.  A VMEM
-scratch accumulator of shape ``[n_seg, bm, bnp]`` carries the peeled
-per-segment sums across K steps: it is zeroed when ``k == 0`` (the
-first visit to an output tile — output revisiting is only legal because
-the K grid axis is sequential) and interleaved back to channel order
-into the output tile on the last K step.  When the whole K reduction
-fits one step (``grid_k == 1``, the common serve case) a scratch-free
-kernel body writes the output tile directly.
+overlaps the next tile's DMA with the current tile's compute.  ``bnp``
+counts packed words (lanes), so every block stays on the TPU's (8, 128)
+tiling.  A VMEM scratch accumulator of shape ``[n_seg, bm, bnp]``
+carries the peeled per-segment sums across K steps: it is zeroed when
+``k == 0`` (the first visit to an output tile — output revisiting is
+only legal because the K grid axis is sequential) and copied to the
+output tile on the last K step.  When the whole K reduction fits one
+step (``grid_k == 1``, the common serve case) a scratch-free kernel body
+writes the output tile directly.  Kernels emit ``[n_seg, M, Np]``
+segment planes; the wrapper interleaves them back to channel order in
+XLA (``peel.interleave``).
 
-Within a K step the packed->peel cadence is preserved: the tile is
-reduced in ``acc_chunk``-column sub-chunks.  The no-overpack peel has
-two formulations, chosen statically per backend (broadcasted shift on
-compiled TPU, unrolled shift+mask in interpret mode — ~1.8x faster
-there); the overpacked peel is inherently sequential (a bottom-up carry
-chain) and shared across backends.  All are bit-identical; the property
-tests and ``tests/diffcheck.py`` cover every placement.
+Within a K step the reduction runs in lane-aligned groups under a
+``fori_loop`` and the packed->peel cadence is preserved inside each
+group (see :mod:`repro.kernels.peel`): int32 operands multiply on the
+VPU, int8 operands on the MXU, and every formulation is bit-identical;
+the property tests and ``tests/diffcheck.py`` cover every placement.
 
 ``block_k=None`` is backend-adaptive: 256 when compiling for TPU (the
 VMEM-residency bound the blocking exists for), whole-K in interpret
 mode, where "VMEM" is host memory and extra grid steps are pure
 overhead (~1.6x at M=8, K=1024 shapes).  The wrapper zero-pads all
-three dimensions up to block multiples, which is exact because zero
-levels contribute nothing to any segment (including the LSB-parity
-planes).
+three dimensions up to block multiples (a K block above
+:data:`~repro.kernels.peel.GROUP` rounds up to a multiple of it), which
+is exact because zero levels contribute nothing to any segment
+(including the LSB-parity planes).
 """
 from __future__ import annotations
 
@@ -68,44 +70,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.peel import interleave, peel_chunks
+from repro.kernels.peel import GROUP, interleave, packed_reduce
 
 
-def _kernel_single_k(a_ref, wp_ref, o_ref, *, n_seg, stride, acc_chunk, overlap,
-                     broadcast_peel):
-    o_ref[...] = interleave(
-        peel_chunks(a_ref[...], wp_ref, n_seg=n_seg, stride=stride,
-                    acc_chunk=acc_chunk, overlap=overlap,
-                    broadcast_peel=broadcast_peel)
-    )
+# whole-K tiles outgrow the default 16 MiB scoped VMEM (a v5e compile at
+# bm=128, K=3072 asked for 19.3 MiB); v5e has 128 MiB
+FUSED_VMEM_BYTES = 64 * 2**20
 
 
-def _kernel_blocked(a_ref, wp_ref, o_ref, acc_ref, *, n_seg, stride, acc_chunk,
-                    overlap, broadcast_peel):
+def _store(o_ref, segs):
+    for d, seg in enumerate(segs):
+        o_ref[d] = seg
+
+
+def _kernel_single_k(a_ref, wp_ref, o_ref, **opts):
+    segs = packed_reduce(a_ref, wp_ref, **opts)
+    _store(o_ref, segs)
+
+
+def _kernel_blocked(a_ref, wp_ref, o_ref, acc_ref, **opts):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += peel_chunks(a_ref[...], wp_ref, n_seg=n_seg,
-                                stride=stride, acc_chunk=acc_chunk,
-                                overlap=overlap, broadcast_peel=broadcast_peel)
+    segs = packed_reduce(a_ref, wp_ref, **opts)
+    for d, seg in enumerate(segs):
+        acc_ref[d] += seg
 
     @pl.when(k_idx == pl.num_programs(2) - 1)
     def _flush():
-        o_ref[...] = interleave(acc_ref[...])
+        o_ref[...] = acc_ref[...]
 
 
-def _kernel_fused(x_ref, wp_ref, o_ref, asum_ref, *, a_bits, n_seg, stride,
-                  acc_chunk, overlap, broadcast_peel):
+def _kernel_fused(x_ref, wp_ref, o_ref, asum_ref, a_ref, *, a_bits, **opts):
+    # quantize the activation tile once into VMEM (clip -> round -> levels)
     n_lvl = (1 << a_bits) - 1
-    a = jnp.round(jnp.clip(x_ref[...], 0.0, 1.0) * n_lvl).astype(jnp.int32)
-    acc = peel_chunks(a, wp_ref, n_seg=n_seg, stride=stride,
-                      acc_chunk=acc_chunk, overlap=overlap,
-                      broadcast_peel=broadcast_peel)
-    o_ref[...] = interleave(acc)
-    asum_ref[...] = jnp.sum(a, axis=1, keepdims=True)
+    a_ref[...] = jnp.round(jnp.clip(x_ref[...], 0.0, 1.0) * n_lvl).astype(jnp.int32)
+    asum_ref[...] = jnp.sum(a_ref[...], axis=1, keepdims=True)
+    segs = packed_reduce(a_ref, wp_ref, **opts)
+    _store(o_ref, segs)
+
+
+def _blocks(m, k, np_, block_m, block_n, block_k):
+    """Clamp the block sizes to the operand and align the K block to the
+    reduction's lane groups; returns ``(bm, bnp, bk, grid)``."""
+    bm = min(block_m, m)
+    bnp = min(block_n, np_)
+    bk = min(block_k, k)
+    if bk > GROUP:
+        bk = -(-bk // GROUP) * GROUP
+    grid = (-(-m // bm), -(-np_ // bnp), -(-k // bk))
+    return bm, bnp, bk, grid
 
 
 def packed_dense_fused_raw(
@@ -127,42 +144,42 @@ def packed_dense_fused_raw(
     the K tile resident), and also emits the per-row level sums needed by
     the zero-point fold.  Returns ``(acc [M, N] int32, a_sum [M] int32)``.
 
-    One kernel launch replaces quantize + a_sum + matmul; the activation
-    quantization recomputes per N block, which is free at serve shapes
-    (grid_n == 1 for d_model <= block_n * n_seg).
+    ``block_n`` counts packed words per block.  One kernel launch
+    replaces quantize + a_sum + matmul; the activation quantization
+    recomputes per N block.
     """
     from repro.kernels.common import pad_to, resolve_interpret
 
     interpret = resolve_interpret(interpret)
     m, k = x.shape
     _, np_ = w_packed.shape
-    bm = min(block_m, m)
-    bnp = min(block_n // n_seg if block_n >= n_seg else 1, np_)
-    grid = (-(-m // bm), -(-np_ // bnp))
-    x = pad_to(x, grid[0] * bm, k)
-    w_packed = pad_to(w_packed, k, grid[1] * bnp)
+    bm, bnp, bk, grid = _blocks(m, k, np_, block_m, block_n, k)
+    x = pad_to(x, grid[0] * bm, bk)
+    w_packed = pad_to(w_packed, bk, grid[1] * bnp)
     kernel = functools.partial(
         _kernel_fused, a_bits=a_bits, n_seg=n_seg, stride=stride,
-        acc_chunk=acc_chunk, overlap=overlap, broadcast_peel=not interpret,
+        acc_chunk=acc_chunk, overlap=overlap,
     )
     acc, a_sum = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=grid[:2],
         in_specs=[
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, bnp), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, bk), lambda i, j: (i, 0)),
+            pl.BlockSpec((bk, bnp), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, bnp * n_seg), lambda i, j: (i, j)),
+            pl.BlockSpec((n_seg, bm, bnp), lambda i, j: (0, i, j)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((grid[0] * bm, grid[1] * bnp * n_seg), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg, grid[0] * bm, grid[1] * bnp), jnp.int32),
             jax.ShapeDtypeStruct((grid[0] * bm, 1), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((bm, bk), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=FUSED_VMEM_BYTES),
         interpret=interpret,
     )(x, w_packed)
-    return acc[:m, : np_ * n_seg], a_sum[:m, 0]
+    return interleave(acc[:, :m, :np_]), a_sum[:m, 0]
 
 
 def packed_matmul_raw(
@@ -181,10 +198,10 @@ def packed_matmul_raw(
     """Integer matmul of levels; returns [M, N] int32 accumulator.
 
     Operands may be int32 (the VPU lane path) or int8 (the MXU lane path
-    — ``kernels.quant_matmul.quant_packed_matmul_raw``); the dot always
-    accumulates int32.  ``overlap=1`` runs the overpacked decode (the
-    weight-LSB planes it needs are a masked view of ``w_packed`` — see
-    the module docstring).
+    — ``kernels.quant_matmul.quant_packed_matmul_raw``); the product
+    always accumulates int32.  ``block_n`` counts packed words per block.
+    ``overlap=1`` runs the overpacked decode (the weight-LSB planes it
+    needs are a masked view of ``w_packed`` — see the module docstring).
     """
     from repro.kernels.common import pad_to, resolve_block_k, resolve_interpret
 
@@ -192,31 +209,26 @@ def packed_matmul_raw(
     m, k = a_lvl.shape
     _, np_ = w_packed.shape
     block_k = resolve_block_k(block_k, k, interpret)  # see Performance note
-    bm = min(block_m, m)
-    bnp = min(block_n // n_seg if block_n >= n_seg else 1, np_)
-    bk = min(block_k, k)
-    grid = (-(-m // bm), -(-np_ // bnp), -(-k // bk))
+    bm, bnp, bk, grid = _blocks(m, k, np_, block_m, block_n, block_k)
     a_lvl = pad_to(a_lvl, grid[0] * bm, grid[2] * bk)
     w_packed = pad_to(w_packed, grid[2] * bk, grid[1] * bnp)
-    opts = dict(
-        n_seg=n_seg, stride=stride, acc_chunk=acc_chunk, overlap=overlap,
-        broadcast_peel=not interpret,
-    )
+    opts = dict(n_seg=n_seg, stride=stride, acc_chunk=acc_chunk, overlap=overlap)
     if grid[2] == 1:
         kernel = functools.partial(_kernel_single_k, **opts)
         scratch = []
     else:
         kernel = functools.partial(_kernel_blocked, **opts)
         scratch = [pltpu.VMEM((n_seg, bm, bnp), jnp.int32)]
-    return pl.pallas_call(
+    acc = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bnp), lambda i, j, kk: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bnp * n_seg), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((grid[0] * bm, grid[1] * bnp * n_seg), jnp.int32),
+        out_specs=pl.BlockSpec((n_seg, bm, bnp), lambda i, j, kk: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((n_seg, grid[0] * bm, grid[1] * bnp), jnp.int32),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(a_lvl, w_packed)[:m, : np_ * n_seg]
+    )(a_lvl, w_packed)
+    return interleave(acc[:, :m, :np_])

@@ -72,18 +72,20 @@ def _gather_body(refs, *, chunk, page_size, out_dtype, quantized):
         # unconditional (one shape, no control flow), only the value dies
         return jnp.where(live, val, jnp.zeros_like(val))
 
-    k_out[...] = tile(pk_ref, ks_ref).reshape(k_out.shape)
-    v_out[...] = tile(pv_ref, vs_ref).reshape(v_out.shape)
+    k_out[0] = tile(pk_ref, ks_ref)
+    v_out[0] = tile(pv_ref, vs_ref)
 
     # per-lane causal/window mask for this block's page_size key positions
-    # (2-D+ iota per the TPU lowering rules; axes: [1, C, 1, page_size])
-    shape = (1, chunk, 1, page_size)
-    kpos = b * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 3)
-    posc = pos_ref[s] + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    # (2-D iota per the TPU lowering rules; axes: [C, page_size]), stored
+    # as int32 in a block-major [1, 1, C, page_size] tile that sits on the
+    # (8, 128) tiling; the wrapper transposes it to lane-major bool
+    shape = (chunk, page_size)
+    kpos = b * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    posc = pos_ref[s] + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     win = win_ref[0]
     causal = kpos <= posc
-    in_win = jnp.where(win > 0, (posc - kpos) < win, True)
-    m_out[...] = causal & in_win
+    in_win = (win <= 0) | ((posc - kpos) < win)  # no i1 select on TPU
+    m_out[0, 0] = (causal & in_win).astype(jnp.int32)
 
 
 @functools.lru_cache(maxsize=64)
@@ -96,7 +98,7 @@ def _gather_fn(n_slots, n_blocks, page_size, width, chunk, out_dtype, quantized,
         scale_spec = table_page_spec(page_size, 1)
         in_specs += [scale_spec, scale_spec]
     view_spec = grid_spec((1, 1, page_size, width), lambda s, b: (s, b, 0, 0))
-    mask_spec = grid_spec((1, chunk, 1, page_size), lambda s, b: (s, 0, b, 0))
+    mask_spec = grid_spec((1, 1, chunk, page_size), lambda s, b: (s, b, 0, 0))
     body = functools.partial(
         _gather_body, chunk=chunk, page_size=page_size,
         out_dtype=out_dtype, quantized=quantized,
@@ -112,7 +114,7 @@ def _gather_fn(n_slots, n_blocks, page_size, width, chunk, out_dtype, quantized,
         out_shape=[
             jax.ShapeDtypeStruct((n_slots, n_blocks, page_size, width), out_dtype),
             jax.ShapeDtypeStruct((n_slots, n_blocks, page_size, width), out_dtype),
-            jax.ShapeDtypeStruct((n_slots, chunk, n_blocks, page_size), jnp.bool_),
+            jax.ShapeDtypeStruct((n_slots, n_blocks, chunk, page_size), jnp.int32),
         ],
         interpret=interpret,
     )
@@ -151,6 +153,6 @@ def paged_gather_raw(
         pos.astype(jnp.int32),
         jnp.asarray(window, jnp.int32).reshape(1),
     )
-    if quantized:
-        return fn(*scalars, pool_k, pool_v, k_scale, v_scale)
-    return fn(*scalars, pool_k, pool_v)
+    pools = (pool_k, pool_v, k_scale, v_scale) if quantized else (pool_k, pool_v)
+    k_view, v_view, mask = fn(*scalars, *pools)
+    return k_view, v_view, jnp.transpose(mask, (0, 2, 1, 3)) != 0

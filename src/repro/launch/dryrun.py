@@ -1,5 +1,7 @@
 import os
 
+# a CPU-only tool: the fake 512-device mesh lives on the host platform
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
 )
@@ -37,7 +39,7 @@ from repro.configs import SHAPES, cells_for, get_config
 from repro.launch.cost import analyze_hlo_collectives, jaxpr_cost
 from repro.configs.registry import ARCHS
 from repro.launch import steps as S
-from repro.launch.mesh import as_shardings, make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
 from repro.parallel.sharding import ShardingRules
 
@@ -114,7 +116,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, fsdp: bool = True,
         "overrides": overrides or {},
     }
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params_shape = S.params_spec_tree(cfg)
         if shape.kind != "train":
             # serving stores weights in bf16 (int8 via --serve-int8)
@@ -140,8 +142,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, fsdp: bool = True,
             b_specs = S.batch_shardings(cfg, rules)
             fn = jax.jit(
                 step,
-                in_shardings=as_shardings(mesh, (p_specs, o_specs, b_specs)),
-                out_shardings=as_shardings(mesh, (P(), p_specs, o_specs)),
+                in_shardings=(p_specs, o_specs, b_specs),
+                out_shardings=(P(), p_specs, o_specs),
                 donate_argnums=(0, 1),  # params/opt update in place
             )
             lowered = fn.lower(params_shape, opt_shape, batch)
@@ -152,8 +154,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, fsdp: bool = True,
             b_specs = S.batch_shardings(cfg, rules)
             fn = jax.jit(
                 step,
-                in_shardings=as_shardings(mesh, (p_specs, b_specs)),
-                out_shardings=as_shardings(mesh, P()),
+                in_shardings=(p_specs, b_specs),
+                out_shardings=P(),
             )
             lowered = fn.lower(params_shape, batch)
         else:  # decode
@@ -166,8 +168,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, fsdp: bool = True,
             pos = jax.ShapeDtypeStruct((), jnp.int32)
             fn = jax.jit(
                 step,
-                in_shardings=as_shardings(mesh, (p_specs, c_specs, P(rules.batch, None), P())),
-                out_shardings=as_shardings(mesh, (P(), c_specs)),
+                in_shardings=(p_specs, c_specs, P(rules.batch, None), P()),
+                out_shardings=(P(), c_specs),
                 donate_argnums=(1,),  # KV/SSM cache updates in place
             )
             lowered = fn.lower(params_shape, cache_shape, tok, pos)
@@ -198,8 +200,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, fsdp: bool = True,
                  + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 2**30, 3),
         }
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per device
-            cost = cost[0] if cost else {}
         record["cost"] = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

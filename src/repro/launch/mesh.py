@@ -14,12 +14,9 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; older jax means all-Auto
-    # axes already, so simply omit the kwarg there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -39,32 +36,3 @@ def make_host_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """`jax.set_mesh(mesh)` where available, else the 0.4.x equivalent of
-    entering the Mesh as the ambient resource environment."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh  # Mesh is itself a context manager on jax<=0.4.x
-
-
-def as_shardings(mesh: jax.sharding.Mesh, tree):
-    """Adapt a pytree of PartitionSpec (or None) for jit in/out_shardings.
-
-    jax >= 0.5 accepts bare PartitionSpecs under ``jax.set_mesh``; on
-    0.4.x they must be wrapped in NamedSharding against the mesh.
-    """
-    if getattr(jax, "set_mesh", None) is not None:
-        return tree
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    def one(leaf):
-        if leaf is None:
-            leaf = PartitionSpec()
-        return NamedSharding(mesh, leaf) if isinstance(leaf, PartitionSpec) else leaf
-
-    return jax.tree.map(
-        one, tree, is_leaf=lambda s: s is None or isinstance(s, PartitionSpec)
-    )

@@ -50,6 +50,11 @@ printed after the run); ``--trace-checkpoint-every N`` rewrites the
 ``--trace`` file every N steps so a crashed run still leaves a
 loadable trace.
 
+The summary line names the device (platform, kind, count).  A
+continuous run exits non-zero when any request ends ``failed``, or when
+a fused step failed for a reason other than injected chaos (a hard
+recovery with chaos off): such a run is not a result.
+
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-130m --tokens 64
   PYTHONPATH=src python -m repro.launch.serve --packed --wbits 4 --abits 4
   PYTHONPATH=src python -m repro.launch.serve --engine static --int8
@@ -68,6 +73,7 @@ from repro.configs.registry import ARCHS
 from repro.launch import steps as S
 from repro.models import transformer as T
 from repro.parallel.sharding import ShardingRules
+from repro.runtime.compile_cache import enable_compile_cache
 
 # weight preparation lives with the unified engine-construction API now;
 # re-exported here because callers historically imported it from serve
@@ -103,20 +109,29 @@ def _serve_static(args, cfg, params, head) -> dict:
     return {"tokens_per_s": tps, "latency_ms_per_step": dt / (args.tokens - 1) * 1e3}
 
 
-def _serve_continuous(args, cfg, params, plan=None) -> dict:
+def peak_bytes_in_use(device=None) -> int | None:
+    """``memory_stats()["peak_bytes_in_use"]`` of ``device`` (default: the
+    first device), or None where the backend keeps no such count."""
+    device = device if device is not None else jax.devices()[0]
+    return (device.memory_stats() or {}).get("peak_bytes_in_use")
+
+
+def _serve_continuous(args, cfg, plan=None) -> dict:
     """Continuous-batching engine over a synthetic same-arrival workload.
 
     Weight preparation is *declared* (``quant=``/``plan=``) rather than
     pre-applied, so ``--mesh DPxMP`` engines get sliced-then-packed
-    per-rank shards from the same flags.
+    per-rank shards from the same flags.  ``build_engine`` initializes
+    the float weights itself (seed 0, on the host), so no float tree
+    outlives weight preparation.
     """
     from repro.serving import EngineConfig, build_engine
 
     ecfg = EngineConfig.from_cli(args)
     quant = "packed" if args.packed else ("int8" if args.int8 else None)
     eng = build_engine(
-        cfg, ecfg, params=params, quant=quant,
-        w_bits=args.wbits, a_bits=args.abits, plan=plan,
+        cfg, ecfg, quant=quant, w_bits=args.wbits, a_bits=args.abits,
+        plan=plan, seed=0,
     )
     rng = jax.random.PRNGKey(2)
     for i in range(args.requests or 2 * args.batch):
@@ -127,6 +142,7 @@ def _serve_continuous(args, cfg, params, plan=None) -> dict:
             deadline=args.deadline, ttft_deadline=args.ttft_deadline,
         )
     eng.warmup()  # compile outside the timed run, like the static loop
+    print(f"peak device bytes after warmup: {peak_bytes_in_use()}")
     server = None
     if ecfg.obs.telemetry_port is not None:
         from repro.obs.server import TelemetryServer
@@ -147,6 +163,7 @@ def _serve_continuous(args, cfg, params, plan=None) -> dict:
     finally:
         if server is not None:
             server.close()
+    print(f"peak device bytes after run: {peak_bytes_in_use()}")
     m["latency_ms_per_step"] = m["wall"] / max(1, m["steps"]) * 1e3
     if eng._attrib is not None:
         summ = eng._attrib.summary()
@@ -321,21 +338,28 @@ def main(argv=None) -> dict:
             "--trace-checkpoint-every rewrites the --trace file mid-run; "
             "add --trace PATH or drop it"
         )
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    enable_compile_cache()
     if engine == "continuous":
         # weight prep is declared to build_engine (so --mesh engines get
         # sliced-then-packed per-rank shards), not pre-applied here
-        out = _serve_continuous(args, cfg, params, plan=plan)
+        out = _serve_continuous(args, cfg, plan=plan)
     else:
-        head = None
-        if plan is not None:
-            from repro.plan import apply_plan
+        from repro.serving.api import host_device
 
-            params, head = apply_plan(params, cfg, plan)
-        elif args.packed:
-            params = quantize_params_packed(params, w_bits=args.wbits, a_bits=args.abits)
-        elif args.int8:
-            params = quantize_params_int8(params)
+        head = None
+        with jax.default_device(host_device()):  # prepare on the host
+            params = T.init_params(jax.random.PRNGKey(0), cfg)
+            if plan is not None:
+                from repro.plan import apply_plan
+
+                params, head = apply_plan(params, cfg, plan)
+            elif args.packed:
+                params = quantize_params_packed(
+                    params, w_bits=args.wbits, a_bits=args.abits
+                )
+            elif args.int8:
+                params = quantize_params_int8(params)
+        params, head = jax.device_put((params, head), jax.devices()[0])
         if head is None and args.packed_head:
             from repro.models.layers import prepack_lm_head
 
@@ -352,10 +376,13 @@ def main(argv=None) -> dict:
     tps = out["tokens_per_s"]
     tps_str = f"{tps:.1f}" if tps is not None else "n/a"
     mesh_str = f" mesh={args.mesh}" if args.mesh else ""
+    dev = jax.devices()[0]
     print(
         f"arch={cfg.name} engine={engine} weights={mode} batch={args.batch}"
         f"{mesh_str} tokens/s={tps_str} "
-        f"latency={out['latency_ms_per_step']:.1f} ms/step"
+        f"latency={out['latency_ms_per_step']:.1f} ms/step "
+        f"platform={dev.platform} device_kind={dev.device_kind} "
+        f"count={len(jax.devices())}"
     )
     if "statuses" in out:
         parts = " ".join(f"{k}={v}" for k, v in sorted(out["statuses"].items()))
@@ -366,6 +393,15 @@ def main(argv=None) -> dict:
             f"quarantines={out.get('quarantines', 0)} "
             f"injected={faults})"
         )
+        chaos_on = bool(
+            args.chaos_step_rate or args.chaos_alloc_rate or args.chaos_nan_rate
+        )
+        n_failed = out["statuses"].get("failed", 0)
+        if n_failed or (out["hard_recoveries"] and not chaos_on):
+            raise SystemExit(
+                f"serve: {n_failed} request(s) failed, "
+                f"{out['hard_recoveries']} hard recoveries"
+            )
     return out
 
 

@@ -19,7 +19,6 @@ Outside a mesh (unit tests), ``moe_apply`` runs the same code with a
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +27,6 @@ from repro.models.layers import NO_QUANT, QuantConfig, dense, rmsnorm, rmsnorm_i
 from repro.parallel.sharding import shard
 
 
-def _axis_size(axis_name) -> int:
-    # jax.lax.axis_size is post-0.4.x; psum(1, axis) is the classic spelling
-    # (constant-folds to the static mesh axis size)
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,7 +140,7 @@ def _local_moe(params: dict, s: MoESpec, x: jax.Array, *, axis_name: str | None,
     tokens are exchanged with all_to_all.
     """
     t_loc, d = x.shape
-    M = _axis_size(axis_name) if axis_name else 1
+    M = jax.lax.axis_size(axis_name) if axis_name else 1
     e_loc = _n_local_experts(params["w_up"])
     E = e_loc * M  # global expert count
     k = s.top_k
@@ -243,7 +236,7 @@ def _local_moe_expert_sharded(params: dict, s: MoESpec, x: jax.Array, *,
     the wire cost is one psum of [t_loc, d] — cheap at decode sizes.
     """
     t_loc, d = x.shape
-    M = _axis_size(axis_name) if axis_name else 1
+    M = jax.lax.axis_size(axis_name) if axis_name else 1
     e_loc = _n_local_experts(params["w_up"])
     E = e_loc * M
     k = s.top_k

@@ -18,7 +18,6 @@ body implements activation rematerialization.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
@@ -258,14 +257,9 @@ def _moe_block(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
         x_spec = P(batch_ax, None, None)
 
-    if hasattr(jax, "shard_map"):
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:  # jax<=0.4.x spelling (check_rep was check_vma's old name)
-        from jax.experimental.shard_map import shard_map as _old_shard_map
-
-        smap = functools.partial(_old_shard_map, check_rep=False)
-    return smap(
-        body, mesh=mesh, in_specs=(p_specs, x_spec), out_specs=x_spec
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(p_specs, x_spec), out_specs=x_spec,
+        check_vma=False,
     )(params, x)
 
 

@@ -41,6 +41,14 @@ from repro.serving.engine import Engine, EngineConfig
 QUANT_MODES = (None, "int8", "packed")
 
 
+def host_device():
+    """The host CPU device.  :func:`build_engine` initializes and packs
+    weights here, so the accelerator never holds the float tree and the
+    packed tree at the same time; the engine then places only what its
+    step reads."""
+    return jax.devices("cpu")[0]
+
+
 def quantize_params_int8(params):
     """Convert every matmul weight to int8 levels + scales.
 
@@ -160,6 +168,10 @@ def build_engine(
     ``head`` injects prepacked LM-head weights (``[mp, ...]``-stacked
     when ``mp > 1``); ``chaos`` is the deprecated keyword shim — prefer
     ``ecfg.chaos``.
+
+    Weight preparation runs on :func:`host_device` (arrays the caller
+    already placed on an accelerator stay there); the engine moves the
+    prepared weights to its own devices.
     """
     if quant not in QUANT_MODES:
         raise ValueError(f"quant must be one of {QUANT_MODES}, got {quant!r}")
@@ -168,6 +180,18 @@ def build_engine(
             "a deployment plan already fixes per-layer quantization; "
             "pass plan= or quant=, not both"
         )
+    with jax.default_device(host_device()):
+        params, head, shard_params = _prepare_weights(
+            cfg, ecfg, params, head, quant, w_bits, a_bits, plan, seed
+        )
+    return Engine(
+        cfg, params, ecfg, rules=rules, head=head, chaos=chaos,
+        shard_params=shard_params,
+    )
+
+
+def _prepare_weights(cfg, ecfg, params, head, quant, w_bits, a_bits, plan, seed):
+    """Float params -> ``(params, head, shard_params)`` for :class:`Engine`."""
     if params is None:
         params = T.init_params(jax.random.PRNGKey(seed), cfg)
     mp = ecfg.mesh.mp
@@ -194,7 +218,4 @@ def build_engine(
             params = quantize_params_packed(
                 params, w_bits=w_bits, a_bits=a_bits, verbose=False
             )
-    return Engine(
-        cfg, params, ecfg, rules=rules, head=head, chaos=chaos,
-        shard_params=shard_params,
-    )
+    return params, head, shard_params

@@ -78,6 +78,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.models import transformer as T
@@ -267,6 +268,20 @@ class EngineConfig:
         )
 
 
+def _place_by_shard(tree, sharding):
+    """Place ``[mp, ...]``-stacked host arrays on a mesh so each device
+    receives only its own slice.  (With a plain ``device_put`` of the
+    host tree, llama3.2-3b at mp=4 peaked at 16.8 GB on the first chip,
+    about the size of the whole stacked tree, against 3.6 GB in use per
+    chip after placement.)"""
+
+    def one(a):
+        host = np.asarray(a)
+        return jax.make_array_from_callback(host.shape, sharding, lambda idx: host[idx])
+
+    return jax.tree.map(one, tree)
+
+
 @dataclasses.dataclass
 class _Replica:
     """One data-parallel shard's host-side serving state: its own page
@@ -375,6 +390,18 @@ class Engine:
         self._local_cfg = (
             cfg if self.mp == 1 else dataclasses.replace(cfg, tp_shards=self.mp)
         )
+        self._mesh = None
+        if self.mp > 1:
+            from repro.launch.mesh import make_host_mesh
+
+            self._mesh = make_host_mesh((self.dp, self.mp), axes=("data", "model"))
+        # replica r of a dp > 1, mp == 1 engine runs on device r when the
+        # host has that many devices (else every replica shares device 0)
+        devs = jax.devices()
+        self._devices = (
+            devs[: self.dp] if self.mp == 1 and len(devs) >= self.dp
+            else [devs[0]] * self.dp
+        )
         if self.mp > 1:
             from repro.parallel.sharding import slice_decode_params, stack_decode_shards
 
@@ -397,12 +424,20 @@ class Engine:
                     )
                     for r in range(self.mp)
                 ])
+            # each model rank's chip holds only its own shard
+            by_rank = NamedSharding(self._mesh, P("model"))
+            self.params = _place_by_shard(self.params, by_rank)
+            if head is not None:
+                head = _place_by_shard(head, by_rank)
+            self._rep_params = [self.params] * self.dp
         else:
-            self.params = params
             if head is None and ecfg.packed_head:
                 head = prepack_lm_head(
                     params["embed"], w_bits=ecfg.head_bits[0], a_bits=ecfg.head_bits[1]
                 )
+            placed = {d: jax.device_put(params, d) for d in set(self._devices)}
+            self._rep_params = [placed[d] for d in self._devices]
+            self.params = self._rep_params[0]
         self._head = head  # kept for segmented re-execution (attribution)
 
         self._ckpt = None
@@ -416,11 +451,6 @@ class Engine:
 
         # -- device state (leading [dp] / [dp, mp] axes when stacked) ------
         self.state = self._init_state()
-        self._mesh = None
-        if self.mp > 1:
-            from repro.launch.mesh import make_host_mesh
-
-            self._mesh = make_host_mesh((self.dp, self.mp), axes=("data", "model"))
         self._build_step(head)
         self._build_reset()
 
@@ -434,6 +464,10 @@ class Engine:
         self.step_retries = 0  # fused-step attempts burned on injected faults
         self.hard_recoveries = 0  # state restores after non-injected step faults
         self.fault_log: list[str] = []  # one line per recovered hard fault
+        # host copy of the latest step's logits, [dp, n_slots, vocab]
+        # (row [r, s] is sampled for slot s of replica r when its chunk
+        # completes the request's sequence)
+        self.last_logits: np.ndarray | None = None
         self._step_time_ewma: float | None = None  # realtime deadline estimator
         # -- observability ------------------------------------------------
         # tracing is a single `is not None` predicate on every hot-path
@@ -455,7 +489,7 @@ class Engine:
         self._attrib: LayerAttributor | None = None
         if ecfg.attrib_every > 0:
             self._attrib = LayerAttributor(
-                cfg, params, head=head, rules=self.rules,
+                cfg, self.params, head=head, rules=self.rules,
                 reps=ecfg.attrib_reps, registry=self.registry,
                 gather=ecfg.gather_backend,
             )
@@ -469,24 +503,29 @@ class Engine:
 
     def _init_state(self):
         """Device state: one tree (dp == mp == 1), a *list* of per-replica
-        trees (dp > 1, mp == 1 — each replica's buffer is dispatched and
-        donated independently), or one ``[dp, mp, ...]``-stacked tree
-        (mp > 1 — the shard_map step owns the whole mesh's state)."""
+        trees (dp > 1, mp == 1 — each replica's buffer lives on its
+        replica's device and is dispatched and donated independently), or
+        one ``[dp, mp, ...]``-stacked tree sharded over the mesh (mp > 1 —
+        the shard_map step owns the whole mesh's state)."""
         ecfg = self.ecfg
-        base = T.init_paged_state(
-            self._local_cfg, ecfg.n_slots, ecfg.pool_pages(), ecfg.page_size,
-            dtype=self.cfg.dtype,
-        )
+
+        def fresh(device):
+            with jax.default_device(device):
+                base = T.init_paged_state(
+                    self._local_cfg, ecfg.n_slots, ecfg.pool_pages(),
+                    ecfg.page_size, dtype=self.cfg.dtype,
+                )
+            return jax.device_put(base, device)
+
         if self.mp > 1:
-            return jax.tree.map(
+            tiled = jax.tree.map(
                 lambda a: jnp.tile(a[None, None], (self.dp, self.mp) + (1,) * a.ndim),
-                base,
+                fresh(self._devices[0]),
             )
+            return jax.device_put(tiled, NamedSharding(self._mesh, P("data", "model")))
         if self.dp > 1:
-            return [base] + [
-                jax.tree.map(jnp.copy, base) for _ in range(self.dp - 1)
-            ]
-        return base
+            return [fresh(d) for d in self._devices]
+        return fresh(self._devices[0])
 
     def _build_step(self, head) -> None:
         """Compile-ready fused step for the engine's mesh mode.
@@ -532,12 +571,7 @@ class Engine:
             return
         # mesh (dp, mp): params+head ride one tuple argument so each model
         # rank gets its own slice (a closed-over head would replicate)
-        if hasattr(jax, "shard_map"):
-            smap = functools.partial(jax.shard_map, check_vma=False)
-        else:  # jax<=0.4.x spelling (check_rep was check_vma's old name)
-            from jax.experimental.shard_map import shard_map as _old_shard_map
-
-            smap = functools.partial(_old_shard_map, check_rep=False)
+        smap = functools.partial(jax.shard_map, check_vma=False)
 
         def _drop_lead(tree):
             return jax.tree.map(lambda a: jnp.squeeze(a, 0), tree)
@@ -571,9 +605,7 @@ class Engine:
         mesh = self._mesh
 
         def mesh_step(*args):
-            from repro.launch.mesh import mesh_context
-
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 return jitted(*args)
 
         self._step = mesh_step
@@ -752,7 +784,7 @@ class Engine:
             return
         for rep in self.replicas:
             args = [
-                self.params,
+                self._rep_params[rep.index],
                 self.state[rep.index] if self.dp > 1 else self.state,
                 jnp.asarray(rep.block_table.as_array()),
                 jnp.zeros((S, C), jnp.int32),
@@ -1027,8 +1059,9 @@ class Engine:
 
         def dispatch():
             """Run the fused step in this engine's mesh mode; returns the
-            logits (``[S, V]`` single-shard, ``[R, S, V]`` otherwise) and
-            swaps the donated state buffer(s) in place."""
+            logits (``[S, V]`` single-shard, ``[R, S, V]`` on the mesh, a
+            list of ``R`` per-replica ``[S, V]`` arrays for dp > 1 with
+            mp == 1) and swaps the donated state buffer(s) in place."""
             if self.mp > 1:
                 table = np.stack([rep.block_table.as_array() for rep in self.replicas])
                 margs = [
@@ -1045,7 +1078,7 @@ class Engine:
                 rows = []
                 for rep in self.replicas:
                     rargs = [
-                        self.params, self.state[rep.index],
+                        self._rep_params[rep.index], self.state[rep.index],
                         jnp.asarray(rep.block_table.as_array()),
                         jnp.asarray(tokens[rep.index]), jnp.asarray(pos[rep.index]),
                     ]
@@ -1053,7 +1086,7 @@ class Engine:
                         rargs.append(jnp.asarray(lens[rep.index]))
                     row, self.state[rep.index] = self._step(*rargs)
                     rows.append(row)
-                return jnp.stack(rows)
+                return rows  # one per replica device; stacked on the host
             out, self.state = self._step(*args)
             return out
         tr = self._trace
@@ -1140,6 +1173,7 @@ class Engine:
         logits_np = np.asarray(logits)  # device sync; [S, V] or [R, S, V]
         if logits_np.ndim == 2:
             logits_np = logits_np[None]
+        self.last_logits = logits_np
         if self._chaos is not None:
             logits_np = np.array(logits_np)  # writable host copy
             for rep in self.replicas:

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.launch import steps as S
-from repro.launch.mesh import as_shardings, make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.models.moe import MoESpec, moe_init, moe_reference
 from repro.parallel.sharding import ShardingRules, use_rules
@@ -55,13 +55,13 @@ def check_train_parity():
     # sharded on a (2, 4) mesh
     mesh = make_host_mesh((2, 4), ("data", "model"))
     rules = ShardingRules(mesh=mesh, batch="data", fsdp=None)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         p_specs = S.param_shardings(jax.eval_shape(lambda: params), rules)
         o_specs = S.param_shardings_opt(None, p_specs)
         b_specs = S.batch_shardings(cfg, rules)
         step = S.make_train_step(cfg, rules, S.TrainStepConfig(n_micro=2))
-        fn = jax.jit(step, in_shardings=as_shardings(mesh, (p_specs, o_specs, b_specs)),
-                     out_shardings=as_shardings(mesh, (P(), p_specs, o_specs)))
+        fn = jax.jit(step, in_shardings=(p_specs, o_specs, b_specs),
+                     out_shardings=(P(), p_specs, o_specs))
         put = lambda tree, specs: jax.tree.map(
             lambda a, sp: jax.device_put(a, NamedSharding(mesh, sp)), tree, specs
         )
@@ -92,7 +92,7 @@ def check_moe_all_to_all():
         mlp_kind="swiglu",
     )
     rules = ShardingRules(mesh=mesh, batch="data", fsdp=None)
-    with mesh_context(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         got = jax.jit(lambda p, v: T._moe_block(p, cfg, v))(params, x)
     np.testing.assert_allclose(np.asarray(want), np.asarray(got), rtol=2e-3, atol=2e-3)
     print("moe all_to_all parity ok")
@@ -106,7 +106,7 @@ def check_checkpoint_reshard(tmp="artifacts/test_ckpt"):
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     mesh_a = make_host_mesh((2, 4), ("data", "model"))
     rules_a = ShardingRules(mesh=mesh_a, batch="data")
-    with mesh_context(mesh_a):
+    with jax.set_mesh(mesh_a):
         specs = S.param_shardings(jax.eval_shape(lambda: params), rules_a)
         sharded = jax.tree.map(
             lambda a, sp: jax.device_put(a, NamedSharding(mesh_a, sp)), params, specs
@@ -116,7 +116,7 @@ def check_checkpoint_reshard(tmp="artifacts/test_ckpt"):
 
     mesh_b = make_host_mesh((4, 2), ("data", "model"))  # elastic rescale
     rules_b = ShardingRules(mesh=mesh_b, batch="data")
-    with mesh_context(mesh_b):
+    with jax.set_mesh(mesh_b):
         specs_b = S.param_shardings(jax.eval_shape(lambda: params), rules_b)
         sh_b = jax.tree.map(lambda sp: NamedSharding(mesh_b, sp), specs_b)
         step, restored = mgr.restore(params, shardings=sh_b)
@@ -139,7 +139,7 @@ def check_moe_decode_psum():
         mlp_kind="swiglu",
     )
     rules = ShardingRules(mesh=mesh, batch="data", fsdp=None)
-    with mesh_context(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         got = jax.jit(lambda p, v: T._moe_block(p, cfg, v))(params, x)
     np.testing.assert_allclose(np.asarray(want), np.asarray(got), rtol=2e-3, atol=2e-3)
     print("moe decode psum parity ok")

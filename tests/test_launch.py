@@ -64,8 +64,6 @@ def test_xla_cost_analysis_undercounts_loops():
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     co = jax.jit(f).lower(x, x).compile()
     ca = co.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     xla_flops = ca.get("flops", 0)
     assert xla_flops < 2 * 2 * 64**3  # ~1 body, not 10
 
@@ -163,7 +161,8 @@ def test_dryrun_cell_end_to_end(tmp_path):
             sys.executable, "-m", "repro.launch.dryrun",
             "--arch", "mamba2-130m", "--shape", "long_500k", "--mesh", "single",
         ],
-        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"},
+        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=1200, cwd=str(ROOT),
     )
     assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]

@@ -227,7 +227,8 @@ def test_multidevice_suite():
     root = pathlib.Path(__file__).resolve().parents[1]
     proc = subprocess.run(
         [sys.executable, str(root / "tests" / "multidevice_checks.py")],
-        env={"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin"},
+        env={"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
         capture_output=True,
         text=True,
         timeout=1200,
