@@ -224,7 +224,8 @@ print(f"  zero leaked pages after chaos: "
 print("== Tracing, live metrics, and plan drift ==")
 # run(trace=...) opens one async span per request (queued -> prefill ->
 # decode, surviving preemption/requeue) and one X span per fused step
-# split into dispatch vs device_wait; the saved JSON loads directly in
+# split into its host phases (batch, upload, dispatch, device_wait,
+# logits_copy, sample); the saved JSON loads directly in
 # Perfetto (https://ui.perfetto.dev) or chrome://tracing.  Disabled
 # tracing costs the hot path one `is not None` check.
 import tempfile
@@ -279,10 +280,9 @@ print("== In-situ per-layer attribution + live telemetry endpoint ==")
 # the pre-step state (the fused step donates its input, so the copy is
 # what keeps re-execution safe) and attributes device time to each layer
 # and its (w_bits, a_bits) pair — inside the serving engine, not a
-# standalone microbenchmark.  Attribution rides the trace as child spans
-# under device_wait on the "layer-attribution" track, and every traced
-# step also emits Perfetto counter tracks (free pages, active/waiting
-# slots, windowed tok/s, preemption + shed totals).
+# standalone microbenchmark.  Every traced step also emits Perfetto
+# counter tracks (free pages, active/waiting slots, windowed tok/s,
+# preemption + shed totals).
 import json as _json
 import urllib.request
 
@@ -313,7 +313,7 @@ print("  scraped mid-serve: " +
 print(f"  /livez: steps={live['steps']} active={live['active_slots']}")
 # the same wiring from the shell — serve with a live endpoint, then
 # curl http://127.0.0.1:9100/metrics while it runs; --trace writes the
-# counter tracks + attribution spans for Perfetto, checkpointed mid-run:
+# counter tracks and step phases for Perfetto, checkpointed mid-run:
 #   PYTHONPATH=src python -m repro.launch.serve --engine continuous \
 #       --telemetry-port 9100 --attrib-every 8 \
 #       --trace artifacts/traces/serve.json --trace-checkpoint-every 64

@@ -45,10 +45,9 @@ Live telemetry (continuous engine only): ``--telemetry-port P`` serves
 and ``/trace?since=N`` (incremental trace flush) on a background thread
 while the run is in flight; ``--attrib-every N`` samples in-situ
 per-layer attribution every N steps (per-layer/bit-pair time shares in
-``/metrics`` and as Perfetto child spans under ``device_wait``, summary
-printed after the run); ``--trace-checkpoint-every N`` rewrites the
-``--trace`` file every N steps so a crashed run still leaves a
-loadable trace.
+``/metrics``, summary printed after the run);
+``--trace-checkpoint-every N`` rewrites the ``--trace`` file every N
+steps so a crashed run still leaves a loadable trace.
 
 The summary line names the device (platform, kind, count).  A
 continuous run exits non-zero when any request ends ``failed``, or when

@@ -199,29 +199,34 @@ def mamba_decode(
     B = x.shape[0]
     H, P, N = s.n_heads, s.head_dim, s.d_state
     h = rmsnorm(params["ln"], x)
-    z, xs, b, c, dt = _project_in(params, s, h, quant)
-    xbc = jnp.concatenate([xs, b, c], axis=-1)  # [B, 1, conv_dim]
-    window = jnp.concatenate([conv_state, xbc], axis=1)  # [B, K, conv_dim]
-    conv_out = jnp.einsum("bkc,kc->bc", window, params["conv_w"].astype(x.dtype)) + params[
-        "conv_b"
-    ].astype(x.dtype)
-    xbc = jax.nn.silu(conv_out)[:, None, :]
-    new_conv_state = window[:, 1:, :]
-    xs = xbc[..., : s.d_inner].reshape(B, H, P)
-    b = xbc[..., s.d_inner : s.d_inner + N].reshape(B, N)
-    c = xbc[..., s.d_inner + N :].reshape(B, N)
-    dt = jax.nn.softplus(dt + params["dt_bias"]).reshape(B, H)
-    a = -jnp.exp(params["a_log"])
-    g = jnp.exp((dt * a).astype(jnp.float32))  # [B, H]
-    contrib = jnp.einsum("bh,bs,bhp->bhsp", dt.astype(jnp.float32), b.astype(jnp.float32), xs.astype(jnp.float32))
-    new_state = ssm_state * g[:, :, None, None] + contrib
-    y = jnp.einsum("bs,bhsp->bhp", c.astype(jnp.float32), new_state).astype(x.dtype)
-    y = y + params["d_skip"].astype(x.dtype)[None, :, None] * xs
-    y = y.reshape(B, 1, s.d_inner) * jax.nn.silu(z)
-    y = _out_norm(params["out_norm"], y, axis_name)
-    out = dense(params["out_proj"], y, name="ssm_out", quant=quant)
-    if axis_name is not None:
-        out = jax.lax.psum(out, axis_name)
+    # named scopes land in each op's op_name metadata in the compiled HLO
+    with jax.named_scope("in_proj"):
+        z, xs, b, c, dt = _project_in(params, s, h, quant)
+    with jax.named_scope("conv"):
+        xbc = jnp.concatenate([xs, b, c], axis=-1)  # [B, 1, conv_dim]
+        window = jnp.concatenate([conv_state, xbc], axis=1)  # [B, K, conv_dim]
+        conv_out = jnp.einsum("bkc,kc->bc", window, params["conv_w"].astype(x.dtype)) + params[
+            "conv_b"
+        ].astype(x.dtype)
+        xbc = jax.nn.silu(conv_out)[:, None, :]
+        new_conv_state = window[:, 1:, :]
+    with jax.named_scope("ssm"):
+        xs = xbc[..., : s.d_inner].reshape(B, H, P)
+        b = xbc[..., s.d_inner : s.d_inner + N].reshape(B, N)
+        c = xbc[..., s.d_inner + N :].reshape(B, N)
+        dt = jax.nn.softplus(dt + params["dt_bias"]).reshape(B, H)
+        a = -jnp.exp(params["a_log"])
+        g = jnp.exp((dt * a).astype(jnp.float32))  # [B, H]
+        contrib = jnp.einsum("bh,bs,bhp->bhsp", dt.astype(jnp.float32), b.astype(jnp.float32), xs.astype(jnp.float32))
+        new_state = ssm_state * g[:, :, None, None] + contrib
+        y = jnp.einsum("bs,bhsp->bhp", c.astype(jnp.float32), new_state).astype(x.dtype)
+        y = y + params["d_skip"].astype(x.dtype)[None, :, None] * xs
+    with jax.named_scope("out_proj"):  # gate, gated norm, projection
+        y = y.reshape(B, 1, s.d_inner) * jax.nn.silu(z)
+        y = _out_norm(params["out_norm"], y, axis_name)
+        out = dense(params["out_proj"], y, name="ssm_out", quant=quant)
+        if axis_name is not None:
+            out = jax.lax.psum(out, axis_name)
     return x + out, new_state, new_conv_state
 
 

@@ -673,6 +673,7 @@ def embed_paged(params: dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     return shard(x, "batch", None, None)
 
 
+@jax.named_scope("decode_paged_layer")
 def decode_paged_layer(
     p,
     cfg: ModelConfig,
@@ -784,7 +785,8 @@ def head_paged(
         # (identical to lane 0 on the legacy C == 1 call sites)
         x_last = x[:, -1, :]
     emb = params.get("head_embed", params["embed"])
-    return L.lm_head(x_last, emb, cfg.dtype, packed=head, axis_name=axis_name)
+    with jax.named_scope("lm_head"):
+        return L.lm_head(x_last, emb, cfg.dtype, packed=head, axis_name=axis_name)
 
 
 def forward_decode_paged(

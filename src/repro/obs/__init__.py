@@ -20,8 +20,7 @@ engine, the kernels, and the benches:
 * :mod:`repro.obs.attrib` — sampled in-situ profiler: every N engine
   steps the fused step is re-executed segmented per layer on a
   donation-safe state copy, attributing real device time to each layer
-  and its ``(w_bits, a_bits)`` pair (registry counters + Perfetto child
-  spans under ``device_wait``).
+  and its ``(w_bits, a_bits)`` pair (registry counters).
 * :mod:`repro.obs.server` — stdlib-HTTP telemetry endpoint on a
   background thread: ``/metrics`` (Prometheus text), ``/livez``
   (windowed live JSON), ``/trace`` (incremental trace-segment flush).

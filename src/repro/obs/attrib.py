@@ -21,10 +21,10 @@ Outputs per sample:
   the ``check_invariants.py --kind attrib`` gate re-checks anyway);
 * accumulation into a shared :class:`~repro.obs.metrics.MetricsRegistry`
   (``repro_attrib_steps_total``, per-layer/per-pair seconds counters) so
-  the telemetry endpoint exposes attribution alongside engine counters;
-* Perfetto child spans subdividing the step's actual ``device_wait``
-  interval proportionally to the measured shares (emitted by the
-  engine, which owns the span timestamps).
+  the telemetry endpoint exposes attribution alongside engine counters.
+
+The shares come from a separate re-execution, so they are not put on the
+trace of the step they were sampled in.
 
 Sampling cost is paid only on sampled steps (one state copy + one
 segmented re-execution); a disabled attributor costs the engine one

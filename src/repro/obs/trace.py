@@ -8,9 +8,10 @@ bound and never raises.  Export is the Chrome trace-event JSON format
 and ``chrome://tracing`` load directly:
 
 * synchronous ``B``/``E`` duration spans and ``X`` complete spans live
-  on ``(pid, tid)`` tracks — the engine puts its fused-step timeline
-  (``step`` with ``dispatch`` / ``device_wait`` children, and sampled
-  per-layer attribution spans inside ``device_wait``) on pid 0;
+  on ``(pid, tid)`` tracks — the engine puts its host phases (``admit``,
+  ``step`` with ``batch`` / ``upload`` / ``dispatch`` / ``device_wait``
+  / ``logits_copy`` / ``sample`` children, ``summary``; see
+  :func:`phase`) on pid 0;
 * asynchronous ``b``/``e`` spans keyed by ``id`` model one track per
   *request* on a separate process (``REQUEST_PID``): a ``request``
   envelope span plus nested phase spans (``queued`` / ``prefill`` /
@@ -49,6 +50,11 @@ reader caught up are reported, not silently skipped).
 Disabled tracing costs the engine one ``is not None`` predicate per
 hook — callers hold ``None`` instead of a recorder; there is no "off"
 mode inside the recorder itself.
+
+The engine's host phases are opened through :func:`phase`, which always
+emits a ``jax.profiler`` annotation named ``engine.<phase>`` (on the
+profiler's clock, beside the device's ops) and, when a recorder is armed,
+records the same span here without the prefix.
 """
 from __future__ import annotations
 
@@ -57,20 +63,22 @@ import pathlib
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 # async request spans share one category so Perfetto groups them by id
 REQUEST_CAT = "request"
 # request tracks live on their own process so the per-request async rows
 # don't interleave with the engine's fused-step timeline
 ENGINE_PID = 0
 REQUEST_PID = 1
-# engine-process thread ids with stable Perfetto names
+# engine-process thread id with a stable Perfetto name
 STEP_TID = 0
-ATTRIB_TID = 1
+# profiler annotations of the engine's host phases are named PHASE_PREFIX + phase
+PHASE_PREFIX = "engine."
 
 _PROCESS_NAMES = {ENGINE_PID: "repro-engine", REQUEST_PID: "repro-requests"}
 _THREAD_NAMES = {
     (ENGINE_PID, STEP_TID): "fused-step",
-    (ENGINE_PID, ATTRIB_TID): "layer-attribution",
     (REQUEST_PID, 0): "requests",
 }
 
@@ -251,3 +259,49 @@ class TraceRecorder:
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(self.to_chrome()) + "\n")
         return path
+
+
+class _RecordedPhase:
+    """A profiler annotation that also records an ``X`` span on the
+    recorder's fused-step track when it closes (unless ``record`` was
+    cleared inside it)."""
+
+    __slots__ = ("_ann", "_tr", "_name", "_args", "_t0", "record")
+
+    def __init__(self, ann, recorder: TraceRecorder, name: str, args: dict):
+        self._ann, self._tr, self._name, self._args = ann, recorder, name, args
+        self.record = True
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = self._tr.now()
+        return self
+
+    def __exit__(self, *exc):
+        if self.record:
+            self._tr.complete(self._name, self._t0, self._tr.now(), tid=STEP_TID,
+                              **self._args)
+        return self._ann.__exit__(*exc)
+
+
+def phase(name: str, recorder: TraceRecorder | None = None, *,
+          step_num: int | None = None, **args):
+    """Context manager around one host phase of the engine.
+
+    Always opens ``jax.profiler.TraceAnnotation("engine.<name>")``, so a
+    running profiler puts the phase on its own clock beside the device's
+    ops (with no profiler running it costs about a microsecond).  With
+    ``step_num`` it is the profiler's step marker, as
+    ``jax.profiler.StepTraceAnnotation`` makes it.  With a ``recorder``,
+    the phase is also recorded there as an ``X`` span named ``name``
+    (carrying ``args``, and ``step=step_num``)."""
+    label = PHASE_PREFIX + name
+    # the event StepTraceAnnotation(label, step_num=n) makes, without its
+    # Python __init__, which costs about a microsecond more
+    ann = (TraceAnnotation(label) if step_num is None
+           else TraceAnnotation(label, _r=1, step_num=step_num))
+    if recorder is None:
+        return ann
+    if step_num is not None:
+        args["step"] = step_num
+    return _RecordedPhase(ann, recorder, name, args)

@@ -86,7 +86,7 @@ from repro.kernels.paged_gather.ops import check_gather_backend
 from repro.models.layers import prepack_lm_head
 from repro.obs.attrib import LayerAttributor
 from repro.obs.metrics import MetricsRegistry, WindowedSeries, percentile
-from repro.obs.trace import TraceRecorder
+from repro.obs.trace import TraceRecorder, phase
 from repro.parallel.sharding import ShardingRules, use_rules
 from repro.serving.chaos import ChaosConfig, ChaosInjector, InjectedFault
 from repro.serving.lifecycle import SLO, TERMINAL_STATUSES, Request
@@ -746,7 +746,7 @@ class Engine:
 
     def _seal_trace(self) -> None:
         """Stamp run metadata into the recorder (the block the trace gates
-        cross-check against) and save it when run() owns the file."""
+        cross-check against); run() then saves it when it owns the file."""
         tr = self._trace
         m = self.metrics()
         tr.metadata.update(
@@ -759,8 +759,6 @@ class Engine:
             chaos_seed=self._chaos.cfg.seed if self._chaos is not None else None,
             dp=self.dp, mp=self.mp,
         )
-        if self._trace_path is not None:
-            tr.save(self._trace_path)
 
     # -- step loop ---------------------------------------------------------
 
@@ -994,25 +992,6 @@ class Engine:
                     if victim is req:
                         break
 
-    def _emit_attrib_spans(self, sample: dict, t0: float, t1: float) -> None:
-        """Perfetto child spans under ``device_wait``: subdivide the fused
-        step's actual device interval proportionally to the measured
-        per-layer shares, on the dedicated attribution thread track."""
-        from repro.obs.trace import ATTRIB_TID
-
-        tr = self._trace
-        span = max(t1 - t0, 0.0)
-        acc = t0
-        for row in sample["layers"]:
-            frac = row["share"] or 0.0
-            dt = span * frac
-            tr.complete(
-                f"layer{row['index']:02d} {row['pair']}", acc, acc + dt,
-                tid=ATTRIB_TID, step=sample["step"], share=frac,
-                seconds=row["seconds"],
-            )
-            acc += dt
-
     def _emit_counter_tracks(self, tr: TraceRecorder) -> None:
         """Per-step Perfetto counter-track samples: pool pressure, slot
         occupancy, windowed throughput, and the monotone fault counters
@@ -1031,65 +1010,64 @@ class Engine:
         tr.counter("shed_total", shed=self.registry.counter(
             "repro_requests_total").value(status="shed"))
 
+    def _upload(self, tokens, pos, lens) -> list:
+        """The step's batch on the device: ``[table, tokens, pos(, lens)]``
+        for one shard or the whole mesh, or one such list per replica for
+        dp > 1 with mp == 1."""
+        C = self.ecfg.chunk_tokens
+
+        def one(table, tok, p, n):
+            out = [jnp.asarray(table), jnp.asarray(tok), jnp.asarray(p)]
+            if C > 1:
+                out.append(jnp.asarray(n))
+            return out
+
+        if self.mp > 1:
+            table = np.stack([rep.block_table.as_array() for rep in self.replicas])
+            return one(table, tokens, pos, lens)
+        if self.dp > 1:
+            return [one(rep.block_table.as_array(), tokens[r], pos[r], lens[r])
+                    for r, rep in enumerate(self.replicas)]
+        return one(self.block_table.as_array(), tokens[0], pos[0], lens[0])
+
+    def _dispatch(self, batch: list, tr: TraceRecorder | None):
+        """Run the fused step in this engine's mesh mode; returns the
+        logits (``[S, V]`` single-shard, ``[R, S, V]`` on the mesh, a list
+        of ``R`` per-replica ``[S, V]`` arrays for dp > 1 with mp == 1)
+        and swaps the donated state buffer(s) in place."""
+        if self.dp > 1 and self.mp == 1:
+            # one dispatch of the same compiled executable per replica:
+            # bit-identical per-request math to the single-device engine
+            rows = []
+            for rep in self.replicas:
+                with phase("dispatch", tr, replica=rep.index):
+                    row, self.state[rep.index] = self._step(
+                        self._rep_params[rep.index], self.state[rep.index],
+                        *batch[rep.index])
+                rows.append(row)
+            return rows  # one per replica device; stacked on the host
+        with phase("dispatch", tr):
+            out, self.state = self._step(self._params_arg(), self.state, *batch)
+        return out
+
     def _step_once(self, now_fn: Callable[[], float]) -> None:
         R, S, C = self.dp, self.ecfg.n_slots, self.ecfg.chunk_tokens
-        if self.ecfg.admit == "on-demand":
-            self._fund_pages(now_fn())
-            if not self._any_active():
-                return  # everything preempted; admission retries next loop
-        tokens = np.zeros((R, S, C), np.int32)
-        pos = np.zeros((R, S), np.int32)
-        lens = np.zeros((R, S), np.int32)
-        for rep, slot, req in self._active_items():
-            chunk, start = req.next_chunk(C)
-            tokens[rep.index, slot, : len(chunk)] = chunk
-            pos[rep.index, slot] = start
-            lens[rep.index, slot] = len(chunk)
-        args = None  # single-shard batch args (also fed to the attributor)
-        if not self._stacked:
-            args = [
-                self.params,
-                self.state,
-                jnp.asarray(self.block_table.as_array()),
-                jnp.asarray(tokens[0]),
-                jnp.asarray(pos[0]),
-            ]
-            if C > 1:
-                args.append(jnp.asarray(lens[0]))
-
-        def dispatch():
-            """Run the fused step in this engine's mesh mode; returns the
-            logits (``[S, V]`` single-shard, ``[R, S, V]`` on the mesh, a
-            list of ``R`` per-replica ``[S, V]`` arrays for dp > 1 with
-            mp == 1) and swaps the donated state buffer(s) in place."""
-            if self.mp > 1:
-                table = np.stack([rep.block_table.as_array() for rep in self.replicas])
-                margs = [
-                    self._params_arg(), self.state, jnp.asarray(table),
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                ]
-                if C > 1:
-                    margs.append(jnp.asarray(lens))
-                out, self.state = self._step(*margs)
-                return out
-            if self.dp > 1:
-                # one dispatch of the same compiled executable per replica:
-                # bit-identical per-request math to the single-device engine
-                rows = []
-                for rep in self.replicas:
-                    rargs = [
-                        self._rep_params[rep.index], self.state[rep.index],
-                        jnp.asarray(rep.block_table.as_array()),
-                        jnp.asarray(tokens[rep.index]), jnp.asarray(pos[rep.index]),
-                    ]
-                    if C > 1:
-                        rargs.append(jnp.asarray(lens[rep.index]))
-                    row, self.state[rep.index] = self._step(*rargs)
-                    rows.append(row)
-                return rows  # one per replica device; stacked on the host
-            out, self.state = self._step(*args)
-            return out
         tr = self._trace
+        with phase("batch", tr):
+            if self.ecfg.admit == "on-demand":
+                self._fund_pages(now_fn())
+                if not self._any_active():
+                    return  # everything preempted; admission retries next loop
+            tokens = np.zeros((R, S, C), np.int32)
+            pos = np.zeros((R, S), np.int32)
+            lens = np.zeros((R, S), np.int32)
+            for rep, slot, req in self._active_items():
+                chunk, start = req.next_chunk(C)
+                tokens[rep.index, slot, : len(chunk)] = chunk
+                pos[rep.index, slot] = start
+                lens[rep.index, slot] = len(chunk)
+        with phase("upload", tr):
+            batch = self._upload(tokens, pos, lens)
         if tr is not None:
             for rep, slot, req in self._active_items():
                 if lens[rep.index, slot] and tr.phase(req.rid) == "prefill":
@@ -1107,16 +1085,11 @@ class Engine:
                 attrib_state = jax.tree.map(jnp.copy, self.state[0])
             else:
                 attrib_state = jax.tree.map(jnp.copy, self.state)
-        t_span = [0.0, 0.0]  # dispatch start / return (tracing only)
         for attempt in range(self.ecfg.max_step_retries + 1):
             try:
                 if self._chaos is not None:
                     self._chaos.before_step()  # raises BEFORE state is touched
-                if tr is not None:
-                    t_span[0] = tr.now()
-                logits = dispatch()
-                if tr is not None:
-                    t_span[1] = tr.now()
+                logits = self._dispatch(batch, tr)
                 break
             except InjectedFault:
                 self.step_retries += 1
@@ -1137,30 +1110,13 @@ class Engine:
         n_active = sum(len(r.scheduler.active) for r in self.replicas)
         self.slot_token_steps += n_active
         self.fed_tokens += int(lens.sum())
-        t_wait = None
-        if tr is not None:
-            # split host dispatch from device wait: block explicitly, then
-            # the np.asarray below is a post-sync host copy
+        with phase("device_wait", tr):
             jax.block_until_ready(logits)
-            t_wait = tr.now()
-            tr.complete("dispatch", t_span[0], t_span[1], step=self.n_steps)
-            tr.complete("device_wait", t_span[1], t_wait, step=self.n_steps)
-            tr.complete("step", t_span[0], t_wait, step=self.n_steps,
-                        active=n_active, fed=int(lens.sum()))
         if attrib_state is not None:
-            if self.dp > 1:
-                sample = self._attrib.sample(
-                    attrib_state, jnp.asarray(self.block_table.as_array()),
-                    jnp.asarray(tokens[0]), jnp.asarray(pos[0]),
-                    jnp.asarray(lens[0]) if C > 1 else None, step=self.n_steps,
-                )
-            else:
-                sample = self._attrib.sample(
-                    attrib_state, args[2], args[3], args[4],
-                    args[5] if C > 1 else None, step=self.n_steps,
-                )
-            if tr is not None:
-                self._emit_attrib_spans(sample, t_span[1], t_wait)
+            # replica 0's batch when dp > 1
+            table, tok, p, *n = batch[0] if self.dp > 1 else batch
+            self._attrib.sample(attrib_state, table, tok, p, n[0] if n else None,
+                                step=self.n_steps)
         if tr is not None:
             self._emit_counter_tracks(tr)
             if (
@@ -1170,10 +1126,11 @@ class Engine:
             ):
                 # crash-durable partial trace; the final seal overwrites it
                 tr.save(self._trace_path)
-        logits_np = np.asarray(logits)  # device sync; [S, V] or [R, S, V]
-        if logits_np.ndim == 2:
-            logits_np = logits_np[None]
-        self.last_logits = logits_np
+        with phase("logits_copy", tr):
+            logits_np = np.asarray(logits)  # [S, V] or [R, S, V]
+            if logits_np.ndim == 2:
+                logits_np = logits_np[None]
+            self.last_logits = logits_np
         if self._chaos is not None:
             logits_np = np.array(logits_np)  # writable host copy
             for rep in self.replicas:
@@ -1185,35 +1142,36 @@ class Engine:
         t = now_fn()
         if self._ckpt is not None and self.n_steps % self.ecfg.snapshot_every == 0:
             self._ckpt.save_async(self.n_steps, self.state)
-        n_new = 0
-        for rep, slot, req in list(self._active_items()):
-            req.n_fed += int(lens[rep.index, slot])
-            if req.n_fed < len(req.seq):
-                continue  # mid-prompt / mid-replay: logits not sampled
-            if tr is not None:
-                tr.req_phase(req.rid, "decode", slot=slot)
-            row = logits_np[rep.index, slot]
-            if not np.isfinite(row).all():
-                # poisoned (or genuinely non-finite) logits about to be
-                # sampled: never emit garbage — quarantine the slot and
-                # replay the request token-identically
-                self._strike(req, t)
-                continue
-            nxt = int(np.argmax(row))
-            if not req.out_tokens:
-                req.t_first_token = t
-            req.out_tokens.append(nxt)
-            n_new += 1
-            if req.done:
-                self._finalize(req, "ok", t)
-        self._win_steps.add(t)
-        if n_new:
-            self._win_tokens.add(t, n_new)
-        reg = self.registry
-        reg.counter("repro_steps_total", "fused engine steps").inc()
-        reg.counter("repro_generated_tokens_total", "sampled tokens").inc(n_new)
-        reg.counter("repro_fed_tokens_total", "valid token lanes fed").inc(
-            float(lens.sum()))
+        with phase("sample", tr):
+            n_new = 0
+            for rep, slot, req in list(self._active_items()):
+                req.n_fed += int(lens[rep.index, slot])
+                if req.n_fed < len(req.seq):
+                    continue  # mid-prompt / mid-replay: logits not sampled
+                if tr is not None:
+                    tr.req_phase(req.rid, "decode", slot=slot)
+                row = logits_np[rep.index, slot]
+                if not np.isfinite(row).all():
+                    # poisoned (or genuinely non-finite) logits about to be
+                    # sampled: never emit garbage — quarantine the slot and
+                    # replay the request token-identically
+                    self._strike(req, t)
+                    continue
+                nxt = int(np.argmax(row))
+                if not req.out_tokens:
+                    req.t_first_token = t
+                req.out_tokens.append(nxt)
+                n_new += 1
+                if req.done:
+                    self._finalize(req, "ok", t)
+            self._win_steps.add(t)
+            if n_new:
+                self._win_tokens.add(t, n_new)
+            reg = self.registry
+            reg.counter("repro_steps_total", "fused engine steps").inc()
+            reg.counter("repro_generated_tokens_total", "sampled tokens").inc(n_new)
+            reg.counter("repro_fed_tokens_total", "valid token lanes fed").inc(
+                float(lens.sum()))
 
     def _replica_watchdog(self, now: float) -> None:
         """dp > 1 only: a replica with waiting work and an empty batch
@@ -1291,13 +1249,14 @@ class Engine:
             if max_steps is not None and self.n_steps >= max_steps:
                 break
             self.ticks += 1
-            for rep in self.replicas:
-                rep.scheduler.release_quarantined(self.ticks)
-                if rep.quarantined and self.ticks >= rep.quarantined_until:
-                    rep.quarantined_until = None
-            self._police(now())
-            self._admit(now())
-            self._replica_watchdog(now())
+            with phase("admit", self._trace):
+                for rep in self.replicas:
+                    rep.scheduler.release_quarantined(self.ticks)
+                    if rep.quarantined and self.ticks >= rep.quarantined_until:
+                        rep.quarantined_until = None
+                self._police(now())
+                self._admit(now())
+                self._replica_watchdog(now())
             if not self._any_active():
                 if self._pending:
                     # nothing running: wait for (or jump to) the next arrival
@@ -1330,7 +1289,14 @@ class Engine:
                 continue
             idle = 0
             t_step0 = time.monotonic()
-            self._step_once(now)
+            n = self.n_steps
+            with phase("step", self._trace, step_num=n + 1) as span:
+                self._step_once(now)
+                if self._trace is not None:
+                    # a step that did not run (all preempted, retries spent,
+                    # hard fault) leaves no recorded span: step spans count
+                    # metrics()["steps"]
+                    span.record = self.n_steps > n
             if realtime:
                 dt = time.monotonic() - t_step0
                 self.registry.histogram(
@@ -1352,9 +1318,12 @@ class Engine:
             if self.ecfg.check_invariants:
                 self.assert_no_leaks()
         self._t_run_end = time.monotonic() - t_wall0
-        out = self.metrics()
-        if self._trace is not None:
-            self._seal_trace()
+        with phase("summary", self._trace):
+            out = self.metrics()
+            if self._trace is not None:
+                self._seal_trace()
+        if self._trace_path is not None:
+            self._trace.save(self._trace_path)
         return out
 
     _realtime = True  # set by run(); _est_service_time default

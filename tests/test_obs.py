@@ -145,12 +145,12 @@ def test_windowed_series_prunes_and_rates():
 # ---------------------------------------------------------------------------
 
 
-def _engine(**kw):
+def _engine(arch="llama3.2-3b", **kw):
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.serving import Engine, EngineConfig
 
-    cfg = get_config("llama3.2-3b", smoke=True)
+    cfg = get_config(arch, smoke=True)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     ecfg = EngineConfig(n_slots=2, page_size=8, max_len=32, chunk_tokens=4, **kw)
     eng = Engine(cfg, params, ecfg)
@@ -224,6 +224,97 @@ def test_traced_chaos_run_reconciles_injections():
 
 
 # ---------------------------------------------------------------------------
+# host phases on the profiler's clock, named scopes in the model step
+# ---------------------------------------------------------------------------
+
+# the host phases inside each engine step, in order
+PHASES = ("batch", "upload", "dispatch", "device_wait", "logits_copy", "sample")
+SCOPES = ("decode_paged_layer", "in_proj", "conv", "ssm", "out_proj", "lm_head")
+
+
+def _profiled_run(log_dir, recorder=None):
+    """Drive a smoke engine one ``run()`` call per step, as the benchmark
+    does, under the JAX profiler; returns the engine and the host events
+    named ``engine.*`` or ``test.run`` as ``(name, start_ns, end_ns,
+    stats)``, in order (an enclosing span before what it encloses)."""
+    from jax.profiler import ProfileData
+
+    eng = _engine("mamba2-130m")
+    eng.warmup()
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        while len(eng.finished) < 3:
+            with jax.profiler.TraceAnnotation("test.run"):
+                eng.run(realtime=False, max_steps=eng.n_steps + 1, trace=recorder)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = pathlib.Path(log_dir).rglob("*.xplane.pb")
+    evs = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+           for plane in ProfileData.from_file(str(path)).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith(("engine.", "test.run"))]
+    return eng, sorted(evs, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(evs, outer):
+    return [e for e in evs if e is not outer and outer[1] <= e[1] and e[2] <= outer[2]]
+
+
+def test_engine_phases_are_profiler_spans(tmp_path):
+    eng, evs = _profiled_run(tmp_path)
+    steps = [e for e in evs if e[0] == "engine.step"]
+    assert [e[3]["step_num"] for e in steps] == list(range(1, eng.n_steps + 1))
+    # the profiler's step marker, as StepTraceAnnotation sets it
+    assert all(e[3]["_r"] == 1 for e in steps)
+    assert len(steps) >= 4
+    for step in steps:
+        # exactly one of each phase, in order, inside its step
+        assert [e[0] for e in _inside(evs, step)] == [f"engine.{p}" for p in PHASES]
+    runs = [e for e in evs if e[0] == "test.run"]
+    assert len(runs) == eng.n_steps
+    for run in runs:
+        names = [e[0] for e in _inside(evs, run)]
+        assert names[0] == "engine.admit" and names[-1] == "engine.summary"
+        assert names.count("engine.summary") == 1 and names.count("engine.step") == 1
+
+
+def test_recorder_phases_share_the_profiler_steps(tmp_path):
+    from repro.obs.trace import ENGINE_PID, STEP_TID
+
+    tr = TraceRecorder()
+    eng, evs = _profiled_run(tmp_path, tr)
+    spans = sorted((e for e in tr.events if e["ph"] == "X"),
+                   key=lambda e: (e["ts"], -e["dur"]))
+    assert all(e["pid"] == ENGINE_PID and e["tid"] == STEP_TID for e in spans)
+    # the same phases, in the same order, without the prefix
+    assert [e["name"] for e in spans] == [e[0].removeprefix("engine.") for e in evs
+                                          if e[0] != "test.run"]
+    rec = {e["args"]["step"]: e["ts"] for e in spans if e["name"] == "step"}
+    prof = {e[3]["step_num"]: e[1] / 1e3 for e in evs if e[0] == "engine.step"}
+    assert sorted(rec) == sorted(prof) == list(range(1, eng.n_steps + 1))
+    # matched by step number, the two clocks keep one offset (microseconds)
+    offsets = [prof[k] - rec[k] for k in rec]
+    assert max(offsets) - min(offsets) < 500.0
+    assert ci.check_trace(tr.to_chrome()) == []
+
+
+def test_model_step_hlo_carries_named_scopes():
+    import re
+
+    import jax.numpy as jnp
+
+    eng = _engine("mamba2-130m")
+    S, C = eng.ecfg.n_slots, eng.ecfg.chunk_tokens
+    zeros = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    text = eng._step.lower(eng.params, eng.state, jnp.asarray(eng.block_table.as_array()),
+                           zeros(S, C), zeros(S), zeros(S)).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope
+
+
+# ---------------------------------------------------------------------------
 # plan drift
 # ---------------------------------------------------------------------------
 
@@ -285,12 +376,12 @@ def test_kernel_timer_records_and_bests():
 
 
 def test_trace_metadata_names_every_used_track():
-    from repro.obs.trace import ATTRIB_TID, ENGINE_PID, REQUEST_PID
+    from repro.obs.trace import ENGINE_PID, REQUEST_PID, STEP_TID
 
     tr = TraceRecorder()
     t0 = tr.now()
     tr.complete("step", t0, tr.now(), step=1)
-    tr.complete("layer00 w5a4", t0, tr.now(), tid=ATTRIB_TID)
+    tr.instant("marker", tid=5)  # a track with no stable name
     tr.req_begin(3)
     tr.req_end(3, "ok")
     ms = tr.name_metadata()
@@ -300,8 +391,8 @@ def test_trace_metadata_names_every_used_track():
     assert rows == [
         ("M", "process_name", ENGINE_PID, 0, "repro-engine"),
         ("M", "process_name", REQUEST_PID, 0, "repro-requests"),
-        ("M", "thread_name", ENGINE_PID, 0, "fused-step"),
-        ("M", "thread_name", ENGINE_PID, ATTRIB_TID, "layer-attribution"),
+        ("M", "thread_name", ENGINE_PID, STEP_TID, "fused-step"),
+        ("M", "thread_name", ENGINE_PID, 5, "tid-5"),
         ("M", "thread_name", REQUEST_PID, 0, "requests"),
     ]
     # to_chrome prepends exactly these before the payload events
@@ -419,16 +510,17 @@ def test_attrib_sampling_on_engine_matches_counters_and_gate(tmp_path):
     from repro.obs.promcheck import check_exposition
 
     assert check_exposition(text) == []
-    # the trace still satisfies the gate, carries child spans on the
-    # attribution track and counter samples every step
+    # the trace still satisfies the gate; its engine spans are the host
+    # phases on the fused-step track (the re-execution's shares are not
+    # put on it) and counter samples come every step
     d = json.loads(out.read_text())
     assert ci.check_trace(d) == []
-    from repro.obs.trace import ATTRIB_TID, ENGINE_PID
+    from repro.obs.trace import ENGINE_PID, STEP_TID
 
-    child = [e for e in d["traceEvents"]
-             if e.get("ph") == "X" and e.get("tid") == ATTRIB_TID
-             and e.get("pid") == ENGINE_PID]
-    assert len(child) == len(at.samples) * eng.cfg.n_layers
+    spans = [e for e in d["traceEvents"]
+             if e.get("ph") == "X" and e.get("pid") == ENGINE_PID]
+    assert {e["tid"] for e in spans} == {STEP_TID}
+    assert {e["name"] for e in spans} == set(PHASES) | {"step", "admit", "summary"}
     counters = [e for e in d["traceEvents"] if e.get("ph") == "C"]
     assert {e["name"] for e in counters} == {
         "pages", "slots", "tokens_per_s_window", "preemptions_total",
