@@ -3,7 +3,11 @@
 Train path: the sequence is split into chunks of ``chunk`` tokens; the
 intra-chunk term is the quadratic masked product of the duality paper,
 the inter-chunk term is a (cheap) ``lax.scan`` over chunk states
-[B, H, P, N].  Decode path: O(1) recurrent state update per token.
+[B, H, P, N].  Decode path: O(1) recurrent state update per token, in
+three phases.  The input phase (norm, ``in_z``/``in_xbc``/``in_dt``) and
+the output phase (gate, gated norm, ``out_proj``) are row-wise, so they
+run once over every token row of a step; only the conv window and the
+SSM recurrence walk the token lanes in order.
 
 The block layout follows mamba2: in_proj -> (z | xBC | dt), causal
 depthwise conv1d(4) on xBC, SSD core, gated RMSNorm, out_proj.
@@ -67,15 +71,16 @@ def mamba_init(key, s: MambaSpec) -> dict:
     }
 
 
-def _project_in(params: dict, s: MambaSpec, h: jax.Array, quant: QuantConfig):
-    z = dense(params["in_z"], h, name="ssm_in", quant=quant)
-    xbc = dense(params["in_xbc"], h, name="ssm_in", quant=quant)
-    dt = dense(params["in_dt"], h, name="ssm_dt", quant=quant)
-    n = s.d_state
-    x = xbc[..., : s.d_inner]
-    b = xbc[..., s.d_inner : s.d_inner + n]
-    c = xbc[..., s.d_inner + n :]
-    return z, x, b, c, dt
+def _project_in(params: dict, x: jax.Array, quant: QuantConfig):
+    """Input phase, row-wise: norm, then (z, xBC, dt) of every row of x
+    [..., d_model]; xBC is the conv's input."""
+    h = rmsnorm(params["ln"], x)
+    # named scopes land in each op's op_name metadata in the compiled HLO
+    with jax.named_scope("in_proj"):
+        z = dense(params["in_z"], h, name="ssm_in", quant=quant)
+        xbc = dense(params["in_xbc"], h, name="ssm_in", quant=quant)
+        dt = dense(params["in_dt"], h, name="ssm_dt", quant=quant)
+    return z, xbc, dt
 
 
 def _conv1d_causal(w: jax.Array, bias: jax.Array, x: jax.Array) -> jax.Array:
@@ -98,9 +103,7 @@ def mamba_train(params: dict, s: MambaSpec, x: jax.Array, *, quant: QuantConfig 
     B, S, _ = x.shape
     H, P, N, Q = s.n_heads, s.head_dim, s.d_state, min(s.chunk, S)
     assert S % Q == 0, "sequence must divide the SSD chunk size"
-    h = rmsnorm(params["ln"], x)
-    z, xs, b, c, dt = _project_in(params, s, h, quant)
-    xbc = jnp.concatenate([xs, b, c], axis=-1)
+    z, xbc, dt = _project_in(params, x, quant)
     xbc = jax.nn.silu(_conv1d_causal(params["conv_w"], params["conv_b"], xbc))
     xs = xbc[..., : s.d_inner].reshape(B, S, H, P)
     b = xbc[..., s.d_inner : s.d_inner + N]
@@ -178,6 +181,58 @@ def _out_norm(params: dict, y: jax.Array, axis_name: str | None, eps: float = 1e
     return (y * jax.lax.rsqrt(var + eps).astype(y.dtype)) * params["g"].astype(y.dtype)
 
 
+def _decode_lane(
+    params: dict,
+    s: MambaSpec,
+    xbc: jax.Array,  # [B, conv_dim] this lane's conv input
+    dt: jax.Array,  # [B, H] this lane's dt projection
+    ssm_state: jax.Array,  # [B, H, N, P] float32
+    conv_state: jax.Array,  # [B, conv_width-1, conv_dim]
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Conv window and SSM update of one token lane, the part of the step
+    that needs lanes in order; returns (y [B, d_inner], ssm_state,
+    conv_state)."""
+    B = xbc.shape[0]
+    H, P, N = s.n_heads, s.head_dim, s.d_state
+    with jax.named_scope("conv"):
+        window = jnp.concatenate([conv_state, xbc[:, None]], axis=1)  # [B, K, conv_dim]
+        conv_out = jnp.einsum("bkc,kc->bc", window, params["conv_w"].astype(xbc.dtype)) + params[
+            "conv_b"
+        ].astype(xbc.dtype)
+        xbc = jax.nn.silu(conv_out)
+        new_conv_state = window[:, 1:, :]
+    with jax.named_scope("ssm"):
+        xs = xbc[:, : s.d_inner].reshape(B, H, P)
+        b = xbc[:, s.d_inner : s.d_inner + N]
+        c = xbc[:, s.d_inner + N :]
+        dt = jax.nn.softplus(dt + params["dt_bias"])
+        a = -jnp.exp(params["a_log"])
+        g = jnp.exp((dt * a).astype(jnp.float32))  # [B, H]
+        contrib = jnp.einsum("bh,bs,bhp->bhsp", dt.astype(jnp.float32), b.astype(jnp.float32), xs.astype(jnp.float32))
+        new_state = ssm_state * g[:, :, None, None] + contrib
+        y = jnp.einsum("bs,bhsp->bhp", c.astype(jnp.float32), new_state).astype(xbc.dtype)
+        y = y + params["d_skip"].astype(xbc.dtype)[None, :, None] * xs
+    return y.reshape(B, s.d_inner), new_state, new_conv_state
+
+
+def _decode_out(
+    params: dict,
+    x: jax.Array,  # [..., d_model] the block's input
+    y: jax.Array,  # [..., d_inner] SSM output of every row
+    z: jax.Array,  # [..., d_inner] gate of every row
+    quant: QuantConfig,
+    axis_name: str | None,
+) -> jax.Array:
+    """Output phase, row-wise: gate, gated norm, projection, residual."""
+    with jax.named_scope("out_proj"):
+        y = y * jax.nn.silu(z)
+        y = _out_norm(params["out_norm"], y, axis_name)
+        out = dense(params["out_proj"], y, name="ssm_out", quant=quant)
+        if axis_name is not None:
+            out = jax.lax.psum(out, axis_name)
+    return x + out
+
+
 def mamba_decode(
     params: dict,
     s: MambaSpec,
@@ -196,38 +251,9 @@ def mamba_decode(
     on one device, and the row-parallel out_proj is psum-reduced before
     the replicated residual add.
     """
-    B = x.shape[0]
-    H, P, N = s.n_heads, s.head_dim, s.d_state
-    h = rmsnorm(params["ln"], x)
-    # named scopes land in each op's op_name metadata in the compiled HLO
-    with jax.named_scope("in_proj"):
-        z, xs, b, c, dt = _project_in(params, s, h, quant)
-    with jax.named_scope("conv"):
-        xbc = jnp.concatenate([xs, b, c], axis=-1)  # [B, 1, conv_dim]
-        window = jnp.concatenate([conv_state, xbc], axis=1)  # [B, K, conv_dim]
-        conv_out = jnp.einsum("bkc,kc->bc", window, params["conv_w"].astype(x.dtype)) + params[
-            "conv_b"
-        ].astype(x.dtype)
-        xbc = jax.nn.silu(conv_out)[:, None, :]
-        new_conv_state = window[:, 1:, :]
-    with jax.named_scope("ssm"):
-        xs = xbc[..., : s.d_inner].reshape(B, H, P)
-        b = xbc[..., s.d_inner : s.d_inner + N].reshape(B, N)
-        c = xbc[..., s.d_inner + N :].reshape(B, N)
-        dt = jax.nn.softplus(dt + params["dt_bias"]).reshape(B, H)
-        a = -jnp.exp(params["a_log"])
-        g = jnp.exp((dt * a).astype(jnp.float32))  # [B, H]
-        contrib = jnp.einsum("bh,bs,bhp->bhsp", dt.astype(jnp.float32), b.astype(jnp.float32), xs.astype(jnp.float32))
-        new_state = ssm_state * g[:, :, None, None] + contrib
-        y = jnp.einsum("bs,bhsp->bhp", c.astype(jnp.float32), new_state).astype(x.dtype)
-        y = y + params["d_skip"].astype(x.dtype)[None, :, None] * xs
-    with jax.named_scope("out_proj"):  # gate, gated norm, projection
-        y = y.reshape(B, 1, s.d_inner) * jax.nn.silu(z)
-        y = _out_norm(params["out_norm"], y, axis_name)
-        out = dense(params["out_proj"], y, name="ssm_out", quant=quant)
-        if axis_name is not None:
-            out = jax.lax.psum(out, axis_name)
-    return x + out, new_state, new_conv_state
+    z, xbc, dt = _project_in(params, x, quant)
+    y, new_state, new_conv_state = _decode_lane(params, s, xbc[:, 0], dt[:, 0], ssm_state, conv_state)
+    return _decode_out(params, x, y[:, None], z, quant, axis_name), new_state, new_conv_state
 
 
 def mamba_decode_chunk(
@@ -243,23 +269,27 @@ def mamba_decode_chunk(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Recurrent step over a C-token chunk (chunked-prefill serving).
 
-    Scans :func:`mamba_decode` over the lane axis so each lane sees the
-    conv/SSM state left by the previous one — token-exact with C separate
+    The input and output phases run once over all ``B * C`` rows, so each
+    projection is one matmul per layer.  Between them a scan over the
+    lane axis carries the conv window and the SSM state, so each lane
+    sees the state left by the previous one: token-exact with C separate
     single-token steps.  Lanes ``j >= lens[b]`` leave sequence ``b``'s
     recurrent state untouched, so decode slots (one valid lane) ride in
     the same jitted iteration as slots prefilling full chunks.
     """
-    B, C, _ = x.shape
+    C = x.shape[1]
+    xt = jnp.moveaxis(x, 1, 0)  # lane-major, so the scan reads whole lanes
+    z, xbc, dt = _project_in(params, xt, quant)
 
-    def body(carry, j):
+    def body(carry, lane):
         st, cv = carry
-        xj = jax.lax.dynamic_slice_in_dim(x, j, 1, axis=1)
-        h, ns, nc = mamba_decode(params, s, xj, st, cv, quant=quant, axis_name=axis_name)
+        xbc_j, dt_j, j = lane
+        y, ns, nc = _decode_lane(params, s, xbc_j, dt_j, st, cv)
         if lens is not None:
             ok = j < lens  # [B]
             ns = jnp.where(ok[:, None, None, None], ns, st)
             nc = jnp.where(ok[:, None, None], nc, cv)
-        return (ns, nc), h[:, 0]
+        return (ns, nc), y
 
-    (ns, nc), hs = jax.lax.scan(body, (ssm_state, conv_state), jnp.arange(C))
-    return jnp.moveaxis(hs, 0, 1), ns, nc
+    (ns, nc), ys = jax.lax.scan(body, (ssm_state, conv_state), (xbc, dt, jnp.arange(C)))
+    return jnp.moveaxis(_decode_out(params, xt, ys, z, quant, axis_name), 0, 1), ns, nc

@@ -7,7 +7,13 @@ import numpy as np
 import pytest
 
 from repro.models import layers as L
-from repro.models.mamba import MambaSpec, mamba_decode, mamba_init, mamba_train
+from repro.models.mamba import (
+    MambaSpec,
+    mamba_decode,
+    mamba_decode_chunk,
+    mamba_init,
+    mamba_train,
+)
 from repro.models.moe import MoESpec, moe_apply, moe_init, moe_reference
 
 
@@ -98,6 +104,109 @@ def test_ssd_chunk_invariance_and_decode():
     np.testing.assert_allclose(
         np.asarray(y4), np.asarray(jnp.concatenate(ys, 1)), rtol=1e-3, atol=1e-4
     )
+
+
+def _lane_block_oracle(p, s, x, ssm_state, conv_state):
+    """The whole Mamba2 block on one lane's rows x [B, 1, d], every phase
+    per lane: the recurrent step as the lane scan used to run it."""
+    f32 = jnp.float32
+    B = x.shape[0]
+    H, P, N = s.n_heads, s.head_dim, s.d_state
+    h = L.rmsnorm(p["ln"], x)
+    z, xbc, dt = (L.dense(p[k], h) for k in ("in_z", "in_xbc", "in_dt"))
+    window = jnp.concatenate([conv_state, xbc], axis=1)
+    conv = jnp.einsum("bkc,kc->bc", window, p["conv_w"].astype(x.dtype))
+    xbc = jax.nn.silu(conv + p["conv_b"].astype(x.dtype))
+    xs = xbc[:, : s.d_inner].reshape(B, H, P)
+    b, c = xbc[:, s.d_inner : s.d_inner + N], xbc[:, s.d_inner + N :]
+    dt = jax.nn.softplus(dt + p["dt_bias"]).reshape(B, H)
+    g = jnp.exp((dt * -jnp.exp(p["a_log"])).astype(f32))
+    contrib = jnp.einsum("bh,bs,bhp->bhsp", dt.astype(f32), b.astype(f32), xs.astype(f32))
+    state = ssm_state * g[:, :, None, None] + contrib
+    y = jnp.einsum("bs,bhsp->bhp", c.astype(f32), state).astype(x.dtype)
+    y = y + p["d_skip"].astype(x.dtype)[None, :, None] * xs
+    y = y.reshape(B, 1, s.d_inner) * jax.nn.silu(z)
+    out = L.dense(p["out_proj"], L.rmsnorm(p["out_norm"], y))
+    return x + out, state, window[:, 1:]
+
+
+def _lane_scan_oracle(p, s, x, ssm_state, conv_state, lens):
+    def body(carry, j):
+        st, cv = carry
+        h, ns, nc = _lane_block_oracle(p, s, jax.lax.dynamic_slice_in_dim(x, j, 1, axis=1), st, cv)
+        ok = j < lens
+        ns = jnp.where(ok[:, None, None, None], ns, st)
+        return (ns, jnp.where(ok[:, None, None], nc, cv)), h[:, 0]
+
+    (st, cv), hs = jax.lax.scan(body, (ssm_state, conv_state), jnp.arange(x.shape[1]))
+    return jnp.moveaxis(hs, 0, 1), st, cv
+
+
+def _mamba_case(weights: str, dtype, B: int, C: int):
+    """Small Mamba2 block (w4a4-packed or float projections), an input
+    chunk and random non-zero recurrent states."""
+    s = MambaSpec(d_model=32, d_state=16, head_dim=8)
+    p = mamba_init(jax.random.PRNGKey(0), s)
+    if weights == "w4a4":
+        for k in ("in_z", "in_xbc", "out_proj"):
+            p[k] = L.quantize_dense_for_packed_serving(p[k], w_bits=4, a_bits=4)
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    x = jax.random.normal(ks[0], (B, C, s.d_model)).astype(dtype)
+    st = jax.random.normal(ks[1], (B, s.n_heads, s.d_state, s.head_dim))
+    cv = jax.random.normal(ks[2], (B, s.conv_width - 1, s.d_inner + 2 * s.d_state)).astype(dtype)
+    return p, s, x, st, cv
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("weights", ["w4a4", "float"])
+def test_mamba_decode_chunk_bitwise_equals_lane_scan(weights, dtype):
+    """Projections once over all B*C rows, conv and SSM lane by lane: the
+    valid lanes' outputs and both new states equal the per-lane block's
+    scan bit for bit, for ragged lens (C, 1, partial, 0); one-lane
+    ``mamba_decode`` equals the per-lane block outright."""
+    C = 8
+    p, s, x, st, cv = _mamba_case(weights, dtype, B=4, C=C)
+    lens = jnp.asarray([C, 1, 3, 0], jnp.int32)
+    got = jax.jit(lambda x, st, cv, n: mamba_decode_chunk(p, s, x, st, cv, lens=n))(x, st, cv, lens)
+    want = jax.jit(lambda x, st, cv, n: _lane_scan_oracle(p, s, x, st, cv, n))(x, st, cv, lens)
+    valid = np.arange(C)[None, :] < np.asarray(lens)[:, None]
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32)[valid], np.asarray(want[0], np.float32)[valid])
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+    got1 = jax.jit(lambda *a: mamba_decode(p, s, *a))(x[:, :1], st, cv)
+    want1 = jax.jit(lambda *a: _lane_block_oracle(p, s, *a))(x[:, :1], st, cv)
+    for g, w in zip(got1, want1):
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+
+
+def _pallas_calls(jaxpr, in_scan: bool = False):
+    """(inside a scan body, eqn) of every ``pallas_call`` in ``jaxpr``,
+    through scan and jit sub-jaxprs but not into the kernels."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield in_scan, eqn
+            continue
+        inner = in_scan or eqn.primitive.name == "scan"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _pallas_calls(sub, inner)
+
+
+def test_mamba_decode_chunk_projects_all_rows_outside_the_lane_scan():
+    """The packed projections run once per block on all S*C rows: three
+    kernels outside the lane scan, none inside it."""
+    S, C = 4, 8
+    p, s, x, st, cv = _mamba_case("w4a4", jnp.bfloat16, B=S, C=C)
+    lens = jnp.asarray([C, 1, 3, 0], jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: mamba_decode_chunk(p, s, *a, lens=lens))(x, st, cv)
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert [in_scan for in_scan, _ in calls] == [False] * 3
+    assert [eqn.invars[0].aval.shape[0] for _, eqn in calls] == [S * C] * 3
 
 
 def test_moe_matches_reference_when_uncapped():
