@@ -2,25 +2,11 @@
 
 Counts are of the algorithm's useful work, whatever implements it: a
 padding row, a masked key or a logit row nobody samples counts nothing.
+What a step issues and needs is the family's (``kernel_calls`` and
+``useful_ops`` of ``families/<family>.py``); what they cost at the
+chip's peaks is here.
 """
 from __future__ import annotations
-
-
-def packed_projections(dm) -> list[tuple[str, int, int]]:
-    """(name, K, N) of each packed projection of one layer."""
-    d, conv = dm.d_model, dm.d_inner + 2 * dm.d_state
-    return [("in_z", d, dm.d_inner), ("in_xbc", d, conv), ("out_proj", dm.d_inner, d)]
-
-
-def packed_params(dm) -> int:
-    """Packed-projection parameters of the whole model."""
-    return dm.n_layers * sum(k * n for _, k, n in packed_projections(dm))
-
-
-def kernel_calls(dm, n_slots: int, chunk: int) -> list[tuple[int, int, int, int]]:
-    """(M, K, N, calls) of the packed kernel per engine step: the Mamba2
-    block runs lane by lane, one ``[S]`` row block per lane."""
-    return [(n_slots, k, n, dm.n_layers * chunk) for _, k, n in packed_projections(dm)]
 
 
 def matmul_least_s(m: int, k: int, n: int, w_bits: int, a_bits: int, peaks: dict) -> float:
@@ -32,29 +18,9 @@ def matmul_least_s(m: int, k: int, n: int, w_bits: int, a_bits: int, peaks: dict
     return max(ops / peaks["int8_ops_per_s"], nbytes / peaks["hbm_bytes_per_s"])
 
 
-def step_kernel_least_s(dm, n_slots, chunk, w_bits, a_bits, peaks) -> float:
-    return sum(c * matmul_least_s(m, k, n, w_bits, a_bits, peaks)
-               for m, k, n, c in kernel_calls(dm, n_slots, chunk))
-
-
-def useful_ops(dm, chunks: list[tuple[int, int]], n_sampled: int) -> tuple[float, float]:
-    """(integer ops, float ops) of one step's useful work.
-
-    ``chunks`` holds (start position, valid tokens) of each slot fed in
-    the step; ``n_sampled`` counts the logit rows that were sampled.
-    Integer ops: the packed projections of every valid token.  Float
-    ops: the Mamba2 dt projection, conv and state update of every valid
-    token, and the LM head of sampled rows.
-    """
-    rows = sum(n for _, n in chunks)
-    per_row = sum(k * n for _, k, n in packed_projections(dm)) * dm.n_layers
-    int_ops = 2.0 * rows * per_row
-    conv = dm.d_inner + 2 * dm.d_state
-    per_tok = (2 * dm.d_model * dm.ssm_heads + 2 * dm.conv_width * conv
-               + 5 * dm.ssm_heads * dm.d_state * dm.head_dim)
-    flt = float(rows * per_tok * dm.n_layers)
-    flt += 2.0 * n_sampled * dm.d_model * dm.vocab
-    return int_ops, flt
+def kernel_least_s(calls, w_bits: int, a_bits: int, peaks: dict) -> float:
+    """Least time of the packed kernel's ``(M, K, N, calls)`` calls."""
+    return sum(c * matmul_least_s(m, k, n, w_bits, a_bits, peaks) for m, k, n, c in calls)
 
 
 def least_s(int_ops: float, flt_ops: float, peaks: dict) -> float:

@@ -16,7 +16,7 @@ default options (as the benchmark traces), the second without its
 Python tracer.  It prints one JSON line (also written under
 ``chiprun_out/``):
 
-    python bench/phases.py --workload mamba2.chat --seed 7 --seconds 20
+    python bench/phases.py --workload <cell> --seed 7 --seconds 20
 """
 from __future__ import annotations
 

@@ -6,8 +6,10 @@ import pytest
 
 from bench import counts, xplane
 from bench.harness import LayerContext, StepRecord, reader
-from bench.model import Dims
+from bench.model import family
 
+ssm = family("ssm")
+Dims = ssm.Dims
 MAMBA = Dims(24, 768, 50432, d_inner=1536, d_state=128, ssm_heads=24, head_dim=64)
 PEAKS = {"bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
 # event names as a TPU v5e trace of the mamba2-130m step shows them
@@ -39,12 +41,25 @@ def test_trace_names_and_packed_kernel_pattern():
 def test_packed_params_hand_counts():
     # Mamba2-130m: in_z 768x1536, in_xbc 768x(1536 + 2*128), out_proj 1536x768
     per = 768 * 1536 + 768 * 1792 + 1536 * 768
-    assert counts.packed_params(MAMBA) == 24 * per == 89_653_248
+    assert ssm.packed_params(MAMBA) == 24 * per == 89_653_248
 
 
 def test_kernel_calls_per_step():
-    mb = counts.kernel_calls(MAMBA, 16, 16)
-    assert {(m, c) for m, _, _, c in mb} == {(16, 24 * 16)} and len(mb) == 3
+    # one call per projection and layer over all 16 x 16 rows, as the
+    # chip trace shows them: 72 calls of M = 256 a step
+    assert ssm.kernel_calls(MAMBA, 16, 16) == [
+        (256, 768, 1536, 24), (256, 768, 1792, 24), (256, 1536, 768, 24)]
+
+
+def test_step_kernel_least_time_hand_count():
+    # every call is bandwidth-bound at M = 256: packed w4 weights, a4
+    # levels in, float32 out, per layer
+    nbytes = ((768 * 1536 / 2 + 256 * 768 / 2 + 256 * 1536 * 4)
+              + (768 * 1792 / 2 + 256 * 768 / 2 + 256 * 1792 * 4)
+              + (1536 * 768 / 2 + 256 * 1536 / 2 + 256 * 768 * 4))
+    least = counts.kernel_least_s(ssm.kernel_calls(MAMBA, 16, 16), 4, 4, PEAKS)
+    assert least == pytest.approx(24 * nbytes / 819e9)
+    assert least * 1e3 == pytest.approx(0.18917, abs=5e-6)
 
 
 def test_matmul_least_time_hand_count():
@@ -61,13 +76,13 @@ def test_matmul_least_time_hand_count():
 
 def test_useful_ops_counts_valid_rows_only():
     # one slot decoding at position 99 (1 token) and one prefilling 16 at 0
-    i_ops, f_ops = counts.useful_ops(MAMBA, [(99, 1), (0, 16)], n_sampled=1)
-    assert i_ops == 2.0 * 17 * counts.packed_params(MAMBA)
+    i_ops, f_ops = ssm.useful_ops(MAMBA, [(99, 1), (0, 16)], n_sampled=1)
+    assert i_ops == 2.0 * 17 * ssm.packed_params(MAMBA)
     # per token and layer: dt projection 768x24, conv 4 taps over 1792
     # channels, state update and read-out over 24 heads x 128 x 64
     per_tok = 2 * 768 * 24 + 2 * 4 * 1792 + 5 * 24 * 128 * 64
     assert f_ops == pytest.approx(17 * 24 * per_tok + 2.0 * 768 * 50432)
-    i0, f0 = counts.useful_ops(MAMBA, [], n_sampled=0)
+    i0, f0 = ssm.useful_ops(MAMBA, [], n_sampled=0)
     assert (i0, f0) == (0.0, 0.0)
 
 
@@ -75,6 +90,7 @@ def test_useful_ops_counts_valid_rows_only():
 class _Model:
     dims: Dims
     engine: dict
+    family: object = ssm
     w_bits: int = 4
     a_bits: int = 4
 
@@ -89,8 +105,8 @@ def test_roofline_and_mfu_at_most_100_on_perfect_trace(dm, shape):
     s, c = shape
     cell = _Cell(_Model(dm, {"n_slots": s, "chunk_tokens": c}))
     steps = [StepRecord(0.0, 1.0, [(0, c)] * s, s, traced=True)] * 3
-    kernel = 3 * counts.step_kernel_least_s(dm, s, c, 4, 4, PEAKS)
-    useful = 3 * counts.least_s(*counts.useful_ops(dm, [(0, c)] * s, s), PEAKS)
+    kernel = 3 * counts.kernel_least_s(ssm.kernel_calls(dm, s, c), 4, 4, PEAKS)
+    useful = 3 * counts.least_s(*ssm.useful_ops(dm, [(0, c)] * s, s), PEAKS)
     # kernels that run at their roofline, back to back; and a stretch that
     # lasts exactly the least time of the step's useful work
     ev = [xplane.Event("run.1", 0.0, kernel, KERNEL_TEXT)]
